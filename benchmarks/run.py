@@ -66,6 +66,8 @@ def main() -> None:
                          "BENCH_<only|all>.json)")
     args = ap.parse_args()
     args.quick = args.quick or args.smoke
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failed = 0
     collected = []

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.data.synthetic import flight_chunks
 from repro.dsl import load_spec, stream
+from repro.launch.cache import enable_compile_cache
 
 CARRIERS = 20
 
@@ -61,6 +62,7 @@ def main() -> None:
                          "long after the run so scrapers can collect the "
                          "final snapshot (CI uses this)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.spec:
         pipe = (load_spec(SPEC_PATH).secure(args.mode)

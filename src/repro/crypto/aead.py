@@ -12,10 +12,12 @@ Batched fast path: :func:`seal_many` / :func:`open_many` process a whole
 (B, n_words) batch in one compiled program, dispatching to the Pallas
 ``kernels/chacha20`` + ``kernels/cwmac`` backends (interpret on CPU,
 compiled on TPU) with the pure-jnp reference as oracle/fallback.  Compiled
-programs are held in a shape-keyed cache — every round of
-``secure_exchange``/``keyed_route``/``sealed_ppermute`` reuses identical
-(B, n_words) shapes, so one compile amortizes over all subsequent rounds
-(:func:`fastpath_stats` exposes the hit/compile counters).
+programs are held in a shape-keyed cache — every window of a stream
+reuses identical (B, n_words) shapes, so one compile amortizes over all
+subsequent windows (:func:`fastpath_stats` exposes the hit/compile
+counters).  :func:`seal_words` / :func:`open_words` are the same bodies
+for code already being traced, such as a mesh shard sealing its own rows
+inside ``secure_exchange``'s ``shard_map``.
 """
 from __future__ import annotations
 
@@ -153,12 +155,16 @@ def _mac2_batch(words, mk, backend):
     return cwmac.mac2_batch(words, mk[:, 0], mk[:, 1], mk[:, 2], mk[:, 3])
 
 
-def _seal_words(key, nonces, words, *, backend):
+def seal_words(key, nonces, words, *, backend=_DEFAULT_BACKEND):
+    """Traceable body of :func:`seal_many`, for code that is already
+    inside a jit or shard_map (a mesh shard sealing its own rows).  It
+    launches nothing itself, so it counts no dispatch."""
     mk, ct = _cipher_pass(key, nonces, words, backend)
     return ct, _mac2_batch(ct, mk, backend)
 
 
-def _open_words(key, nonces, cts, tags, *, backend):
+def open_words(key, nonces, cts, tags, *, backend=_DEFAULT_BACKEND):
+    """Traceable body of :func:`open_many` (see :func:`seal_words`)."""
     mk, pt = _cipher_pass(key, nonces, cts, backend)
     expect = _mac2_batch(cts, mk, backend)
     return pt, jnp.all(expect == tags, axis=-1)
@@ -183,7 +189,7 @@ def _cached_program(op: str, B: int, n_words: int, backend: str,
     fn = _COMPILE_CACHE.get(ck)
     if fn is None:
         _FP_COMPILES.inc()
-        impl = {"seal": _seal_words, "open": _open_words,
+        impl = {"seal": seal_words, "open": open_words,
                 "mac2": _mac2_words}.get(op)
         if impl is None:                       # mackeys takes no backend kw
             fn = jax.jit(_mac_keys_rows)

@@ -11,7 +11,7 @@ concerns, one module each:
 * :mod:`repro.dist.pipeline_parallel`  — GPipe-style microbatch schedule
   whose stage boundaries are sealed with the ChaCha20/CW-MAC channel.
 
-``repro.dist.compat`` papers over jax version differences (``shard_map``
-moved out of ``jax.experimental`` and renamed ``check_rep``->``check_vma``).
+``repro.dist.compat`` holds the repo's one ``shard_map`` spelling, and
+every mesh is built by :func:`repro.launch.mesh.make_mesh` (Auto axes).
 """
 from repro.dist.meshctx import MeshContext, local_mesh_context  # noqa: F401

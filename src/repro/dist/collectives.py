@@ -152,6 +152,33 @@ def _resolve_session(key, step: Optional[int],
     return key, step * n_counters
 
 
+@functools.lru_cache(maxsize=8)
+def _sealed_round(mesh, axis: str):
+    """One compiled program per (mesh, axis): every shard seals its own
+    mailbox row, the packed ct+tags cross the mesh in ONE all_to_all, and
+    every shard opens the row it received.
+
+    Sealing and opening run inside the shard_map block because a Pallas
+    kernel cannot be partitioned: outside it, a mesh-sharded mailbox
+    reaches the cipher kernel as one global array, which the TPU
+    compiler refuses.  jit retraces per mailbox shape on its own.
+    """
+    row = P(axis, None, None)
+
+    def block(kw, words, nonces_out, nonces_in):  # (8,), (1, W, n), (1, W, 3)
+        n = words.shape[-1]
+        ct, tags = aead.seal_words(kw, nonces_out[0], words[0])
+        payload = jnp.concatenate([ct, tags], axis=-1)        # (W, n + 2)
+        recv = jax.lax.all_to_all(payload, axis, 0, 0, tiled=True)
+        pt, ok = aead.open_words(kw, nonces_in[0], recv[:, :n], recv[:, n:])
+        return pt[None], ok[None]
+
+    return jax.jit(shard_map(block, mesh=mesh,
+                             in_specs=(P(), row, row, row),
+                             out_specs=(row, P(axis, None)),
+                             check_vma=False))
+
+
 def secure_exchange(x: jax.Array, mesh, axis: str = "model", *,
                     key, step: Optional[int] = None, tracer=NULL_TRACER
                     ) -> Tuple[jax.Array, jax.Array]:
@@ -163,17 +190,17 @@ def secure_exchange(x: jax.Array, mesh, axis: str = "model", *,
     reusing it reuses every (key, nonce) pair, i.e. a two-time pad.
 
     Each (src=i, dst=j) sub-block is sealed with counter
-    ``(step*W + i)*W + j`` before the collective and opened (MAC-checked)
+    ``(step*W + i)*W + j`` on the source shard and opened (MAC-checked)
     on the destination shard.  ``x`` must be a 4-byte dtype (words are a
     same-width bitcast).  Returns ``(y, ok)`` with ``y[j, i]`` the opened
     block worker j received from i and ``ok[j, i]`` its MAC verdict.
 
-    All W² blocks are sealed by ONE compiled :func:`repro.crypto.aead.
-    seal_many` program (shape-keyed compile cache: every round reuses the
-    same (W², n_words) signature, so the compile amortizes across rounds),
-    and the ciphertext + tags are packed into a single sealed payload so
-    each round issues exactly ONE :func:`exchange` collective.  The wire
-    still only ever carries ciphertext and MAC tags.
+    The round is ONE compiled program (:func:`_sealed_round`): each shard
+    seals its W outgoing blocks with the batched AEAD body
+    (:func:`repro.crypto.aead.seal_words`), ciphertext + tags are packed
+    into a single payload so the round issues exactly ONE all_to_all, and
+    each shard opens its W incoming blocks.  The wire still only ever
+    carries ciphertext and MAC tags.
     """
     W = int(mesh.shape[axis])
     key, base = _resolve_session(key, step, W * W)
@@ -184,27 +211,21 @@ def secure_exchange(x: jax.Array, mesh, axis: str = "model", *,
     n_words = math.prod(blk_shape) if blk_shape else 1
     kw = jnp.asarray(key.key)
 
+    _EXCHANGE_CALLS.inc()
+    _DISPATCHES.inc()
+    _DISP_EXCHANGE.inc()
     with tracer.span("dist.secure_exchange", cat="dispatch", track="dist",
                      W=W, n_words=n_words, base_counter=int(base)):
-        flat = x.reshape(W * W, n_words)
+        flat = x.reshape(W, W, n_words)
         words = flat if x.dtype == jnp.uint32 else \
             jax.lax.bitcast_convert_type(flat, jnp.uint32)
-        nonces = _route_nonces_base(W, base)              # (W*W, 3) [src, dst]
-        ct, tags = aead.seal_many(kw, nonces, words)      # one program
-
-        # pack ciphertext + tags into one payload: ONE collective per round
-        payload = jnp.concatenate([ct, tags],
-                                  axis=-1).reshape(W, W, n_words + 2)
-        payload_r = exchange(payload, mesh, axis,
-                             tracer=tracer).reshape(W * W, n_words + 2)
-
+        nonces = _route_nonces_base(W, base).reshape(W, W, 3)  # [src, dst]
         # inbox[dst, src] was sealed with the (src, dst) counter
-        nonces_in = nonces.reshape(W, W, 3).swapaxes(0, 1).reshape(W * W, 3)
-        pt, ok = aead.open_many(kw, nonces_in, payload_r[:, :n_words],
-                                payload_r[:, n_words:])
+        pt, ok = _sealed_round(mesh, axis)(kw, words, nonces,
+                                           nonces.swapaxes(0, 1))
         out = pt if x.dtype == jnp.uint32 else \
             jax.lax.bitcast_convert_type(pt, x.dtype)
-        return out.reshape(W, W, *blk_shape), ok.reshape(W, W)
+        return out.reshape(W, W, *blk_shape), ok
 
 
 def _consistent_hash(k: jax.Array) -> jax.Array:

@@ -1,39 +1,15 @@
-"""Version-compat shims for jax distribution APIs.
+"""The repo's one spelling of ``jax.shard_map``.
 
-The repo writes against the modern spelling (``jax.shard_map`` with a
-``check_vma=`` keyword); older installs ship
-``jax.experimental.shard_map.shard_map`` with ``check_rep=`` instead.
-Every repro call site routes through this module so the rest of the code
-uses exactly one spelling.
+Every repro call site routes through :func:`shard_map`, whose only
+difference from ``jax.shard_map`` is that the varying-manual-axes check
+is off unless asked for.
 """
 from __future__ import annotations
-
-import inspect
 
 import jax
 
 
-def _resolve_shard_map():
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn  # jax < 0.6
-    return fn
-
-
-_SHARD_MAP = _resolve_shard_map()
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_SHARD_MAP).parameters)
-
-
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` under any supported jax version.
-
-    ``check_vma`` maps onto the old ``check_rep`` flag when needed (they
-    gate the same replication/varying-manual-axes check).
-    """
-    kw = {}
-    if "check_vma" in _SHARD_MAP_PARAMS:
-        kw["check_vma"] = check_vma
-    elif "check_rep" in _SHARD_MAP_PARAMS:
-        kw["check_rep"] = check_vma
-    return _SHARD_MAP(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+    """``jax.shard_map`` with ``check_vma`` defaulting to False."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

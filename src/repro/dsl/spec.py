@@ -31,13 +31,12 @@ the paper's key), and ``constraint`` — ``"sgx"`` or the paper's literal
 ``"type==sgx"`` mean enclave placement; anything else (or absent) means
 unconstrained.
 
-Python 3.10 has no ``tomllib``; a minimal built-in parser covers the
-subset above (sections, array-of-table headers, scalar ``key = value``)
-and ``tomllib`` is used when available.
+TOML text is parsed by stdlib ``tomllib``.
 """
 from __future__ import annotations
 
 import os
+import tomllib
 from typing import Any, Dict, List, Optional, Union
 
 from repro.dsl.builder import StreamBuilder, stream
@@ -62,85 +61,13 @@ class SpecError(ValueError):
 # --------------------------------------------------------------- parsing
 
 
-def _parse_scalar(v: str, where: str) -> Any:
-    v = v.strip()
-    if len(v) >= 2 and v[0] == v[-1] and v[0] in "\"'":
-        return v[1:-1]
-    if v in ("true", "false"):
-        return v == "true"
-    try:
-        return int(v)
-    except ValueError:
-        pass
-    try:
-        return float(v)
-    except ValueError:
-        raise SpecError(f"{where}: cannot parse value {v!r} "
-                        f"(expected string/int/float/bool)") from None
-
-
-def _strip_comment(line: str) -> str:
-    out, quote = [], None
-    for ch in line:
-        if quote:
-            if ch == quote:
-                quote = None
-        elif ch in "\"'":
-            quote = ch
-        elif ch == "#":
-            break
-        out.append(ch)
-    return "".join(out)
-
-
-def _parse_mini_toml(text: str) -> Dict[str, Any]:
-    """Minimal TOML subset parser (py<3.11 fallback): ``[a.b]`` tables,
-    ``[[a]]`` arrays of tables, scalar ``key = value`` pairs.  Table
-    order is preserved (dict insertion order) — stage order is
-    significant."""
-    root: Dict[str, Any] = {}
-    cur = root
-    for ln, raw in enumerate(text.splitlines(), 1):
-        line = _strip_comment(raw).strip()
-        if not line:
-            continue
-        where = f"line {ln}"
-        if line.startswith("[[") and line.endswith("]]"):
-            path = line[2:-2].strip().split(".")
-            parent = root
-            for p in path[:-1]:
-                parent = parent.setdefault(p, {})
-            arr = parent.setdefault(path[-1], [])
-            if not isinstance(arr, list):
-                raise SpecError(f"{where}: {'.'.join(path)!r} is both a "
-                                f"table and an array of tables")
-            cur = {}
-            arr.append(cur)
-        elif line.startswith("[") and line.endswith("]"):
-            path = line[1:-1].strip().split(".")
-            parent = root
-            for p in path[:-1]:
-                parent = parent.setdefault(p, {})
-            cur = parent.setdefault(path[-1], {})
-            if not isinstance(cur, dict):
-                raise SpecError(f"{where}: {'.'.join(path)!r} redefined "
-                                f"as a table")
-        elif "=" in line:
-            k, v = line.split("=", 1)
-            cur[k.strip()] = _parse_scalar(v, where)
-        else:
-            raise SpecError(f"{where}: cannot parse {raw.strip()!r}")
-    return root
-
-
 def parse_toml(text: str) -> Dict[str, Any]:
-    """Parse TOML text — stdlib ``tomllib`` when present (3.11+), the
-    built-in subset parser otherwise."""
+    """Parse TOML text with stdlib ``tomllib``; a malformed document
+    raises :class:`SpecError`."""
     try:
-        import tomllib
-    except ModuleNotFoundError:
-        return _parse_mini_toml(text)
-    return tomllib.loads(text)
+        return tomllib.loads(text)
+    except tomllib.TOMLDecodeError as e:
+        raise SpecError(f"cannot parse TOML spec: {e}") from None
 
 
 # --------------------------------------------------------------- loading

@@ -43,7 +43,7 @@ def _chacha_rows_kernel(key_ref, nonce_ref, ctr_ref, data_ref, out_ref):
     """
     key = [key_ref[:, i] for i in range(8)]       # 8 x (rows,)
     nonce = [nonce_ref[:, i] for i in range(3)]   # 3 x (rows,)
-    counters = ctr_ref[...]                       # (rows,)
+    counters = ctr_ref[:, 0]                      # (rows,)
     ks = keystream_vectors(key, nonce, counters)  # 16 x (rows,)
     out_ref[...] = data_ref[...] ^ jnp.stack(ks, axis=-1)
 
@@ -55,6 +55,8 @@ def chacha20_xor_rows(keys: jax.Array, nonces: jax.Array, counters: jax.Array,
     """XOR (R, 16) u32 rows with per-row keystream blocks.
 
     keys: (R, 8); nonces: (R, 3); counters: (R,).  R % block_rows == 0.
+    The counters enter the kernel as an (R, 1) column: a 1-D block of
+    ``block_rows`` does not match the TPU's tiling of a long u32 vector.
     """
     R = data_rows.shape[0]
     assert R % block_rows == 0, (R, block_rows)
@@ -65,13 +67,14 @@ def chacha20_xor_rows(keys: jax.Array, nonces: jax.Array, counters: jax.Array,
         in_specs=[
             pl.BlockSpec((block_rows, 8), lambda i: (i, 0)),
             pl.BlockSpec((block_rows, 3), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
+            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
             pl.BlockSpec((block_rows, 16), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, 16), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(data_rows.shape, U32),
         interpret=interpret,
-    )(keys.astype(U32), nonces.astype(U32), counters.astype(U32), data_rows)
+    )(keys.astype(U32), nonces.astype(U32),
+      counters.astype(U32).reshape(R, 1), data_rows)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))

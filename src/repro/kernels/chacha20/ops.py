@@ -5,15 +5,11 @@ the flat-words <-> (N,16)-blocks framing.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.chacha20.chacha20 import chacha20_xor_blocks, \
     chacha20_xor_rows
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def xor_rows(key, nonces, counters, rows, *, block_rows: int = 256):
@@ -32,7 +28,7 @@ def xor_rows(key, nonces, counters, rows, *, block_rows: int = 256):
         counters = jnp.pad(counters, (0, pad))
         rows = jnp.pad(rows, ((0, pad), (0, 0)))
     out = chacha20_xor_rows(keys, nonces, counters, rows,
-                            block_rows=block_rows, interpret=not _on_tpu())
+                            block_rows=block_rows, interpret=interpret_mode())
     return out[:R]
 
 
@@ -45,7 +41,7 @@ def encrypt_words(key, nonce, words, counter0: int = 1, *,
     padded = jnp.pad(words, (0, total - n)).reshape(-1, 16)
     out = chacha20_xor_blocks(key, nonce, counter0, padded,
                               block_rows=block_rows,
-                              interpret=not _on_tpu())
+                              interpret=interpret_mode())
     return out.reshape(-1)[:n]
 
 

@@ -4,10 +4,11 @@ The MAC tag is Σ_i limb_i · r^(n-i) + s.  Factoring by tile t of TS limbs:
 
     tag = Σ_t  r^(TS·(T-1-t)) · P_t,     P_t = Σ_j limb_{t,j} · r^(TS-j)
 
-Each grid program computes one P_t from a VMEM tile using a precomputed
-(TS,) powers vector (r^TS .. r^1); the per-tile scalar factors and the
-final fold are O(T) scalar mulmods done in jnp (ops.py).  Integer-only
-32-bit arithmetic throughout — see repro.crypto.cwmac for the field math.
+Each grid program reduces one (8-row, TS-limb) VMEM tile against the rows'
+precomputed powers (r^TS .. r^1) down to 128 lane partials; folding those
+into P_t, the per-tile factors and the final Horner pass are done in jnp
+(ops.py).  Integer-only 32-bit arithmetic throughout — see
+repro.crypto.cwmac for the field math.
 """
 from __future__ import annotations
 
@@ -44,67 +45,46 @@ def _mulmod(a, b):
     return acc
 
 
-def _mac_tile_kernel(limbs_ref, pows_ref, out_ref, *, tile: int):
-    terms = _mulmod(limbs_ref[...], pows_ref[...])   # (tile,) u32 < p
-    # log-depth tree add-mod within the tile
-    acc = terms
-    n = tile
-    while n > 1:
-        half = n // 2
-        acc = _addmod(acc[:half], acc[half:n])
-        n = half
-    out_ref[0] = acc[0]
+LANES = 128
+ROWS = 8   # one (8, 128) u32 tile: the smallest block Mosaic accepts
 
 
-def _mac_tile_batch_kernel(limbs_ref, pows_ref, out_ref, *, tile: int):
-    terms = _mulmod(limbs_ref[0], pows_ref[0])   # (tile,) u32 < p
-    acc = terms
-    n = tile
-    while n > 1:
+def _mac_tile_batch_kernel(limbs_ref, pows_ref, out_ref):
+    acc = _mulmod(limbs_ref[...], pows_ref[...])   # (ROWS, tile) u32 < p
+    # log-depth tree add-mod across lane-aligned halves; the last 128
+    # lane partials are folded by the caller
+    n = acc.shape[1]
+    while n > LANES:
         half = n // 2
-        acc = _addmod(acc[:half], acc[half:n])
+        acc = _addmod(acc[:, :half], acc[:, half:n])
         n = half
-    out_ref[0, 0] = acc[0]
+    out_ref[...] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def mac_partials_batch(limbs: jax.Array, powers: jax.Array, *,
                        tile: int = 4096, interpret: bool = True) -> jax.Array:
-    """Per-row tiled partials: limbs (B, N) u32 < p with N % tile == 0;
-    powers (B, tile) per-row [r_b^TS .. r_b^1].  Returns (B, N/tile)
-    partials — one grid sweep covers every (row, tile) pair."""
+    """Per-row tiled lane partials: limbs (B, N) u32 < p with B % 8 == 0
+    and N % tile == 0; powers (B, tile) per-row [r_b^TS .. r_b^1].
+
+    tile is a power of two >= 128.  Returns (B, T * 128) with T = N / tile:
+    for row b and tile t, the lanes ``[t*128, (t+1)*128)`` add up (mod p)
+    to that tile's partial P_t.  One grid sweep covers every (8-row, tile)
+    block, and every block is made of whole (8, 128) tiles.
+    """
     B, N = limbs.shape
-    assert N % tile == 0 and (tile & (tile - 1)) == 0, (N, tile)
-    grid = (B, N // tile)
+    assert B % ROWS == 0, (B, ROWS)
+    assert N % tile == 0 and tile >= LANES and (tile & (tile - 1)) == 0, \
+        (N, tile)
+    T = N // tile
     return pl.pallas_call(
-        functools.partial(_mac_tile_batch_kernel, tile=tile),
-        grid=grid,
+        _mac_tile_batch_kernel,
+        grid=(B // ROWS, T),
         in_specs=[
-            pl.BlockSpec((1, tile), lambda b, t: (b, t)),
-            pl.BlockSpec((1, tile), lambda b, t: (b, 0)),
+            pl.BlockSpec((ROWS, tile), lambda b, t: (b, t)),
+            pl.BlockSpec((ROWS, tile), lambda b, t: (b, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda b, t: (b, t)),
-        out_shape=jax.ShapeDtypeStruct((B, N // tile), U32),
-        interpret=interpret,
-    )(limbs, powers)
-
-
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def mac_partials(limbs: jax.Array, powers: jax.Array, *, tile: int = 4096,
-                 interpret: bool = True) -> jax.Array:
-    """limbs: (N,) u32 < p, N % tile == 0; powers: (tile,) = [r^TS..r^1].
-    Returns (N/tile,) per-tile partials P_t."""
-    N = limbs.shape[0]
-    assert N % tile == 0 and (tile & (tile - 1)) == 0, (N, tile)
-    grid = (N // tile,)
-    return pl.pallas_call(
-        functools.partial(_mac_tile_kernel, tile=tile),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((tile,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((N // tile,), U32),
+        out_specs=pl.BlockSpec((ROWS, LANES), lambda b, t: (b, t)),
+        out_shape=jax.ShapeDtypeStruct((B, T * LANES), U32),
         interpret=interpret,
     )(limbs, powers)

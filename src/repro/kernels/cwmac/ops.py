@@ -1,47 +1,57 @@
 """Public op: full CW-MAC via the tiled Pallas kernel + jnp combine."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from repro.crypto.cwmac import _to_limbs, addmod, mulmod, r_powers, \
-    r_powers_batch, to_limbs_batch
-from repro.kernels.cwmac.cwmac import mac_partials, mac_partials_batch
+from repro.crypto.cwmac import addmod, mulmod, r_powers_batch, to_limbs_batch
+from repro.kernels import interpret_mode
+from repro.kernels.cwmac.cwmac import LANES, ROWS, mac_partials_batch
 
 U32 = jnp.uint32
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _pick_tile(n_limbs: int, tile: int) -> int:
-    """Largest power-of-two tile <= requested that doesn't over-pad tiny
-    messages (padding is always to a whole number of tiles)."""
-    t = 8
+    """Smallest power-of-two tile >= 128 lanes that covers the message,
+    capped at the requested tile (padding is always to a whole number of
+    tiles, so tiny messages pad to one 128-lane tile)."""
+    t = LANES
     while t < tile and t < n_limbs:
         t *= 2
     return t
 
 
+def _fold_lanes(x: jax.Array) -> jax.Array:
+    """(..., w) lane partials -> (...,) add-mod over the last axis."""
+    while x.shape[-1] > 1:
+        half = x.shape[-1] // 2
+        x = addmod(x[..., :half], x[..., half:])
+    return x[..., 0]
+
+
+@functools.partial(jax.jit, static_argnames=("tile",))
 def mac_batch(words: jax.Array, r: jax.Array, s: jax.Array, *,
               tile: int = 4096) -> jax.Array:
     """Row-wise kernel-tiled MAC: (B, N) words under (B,) keys -> (B,) tags.
 
-    Same factoring as :func:`mac` but the partials kernel sweeps a
-    (B, T) grid, so one launch MACs the whole batch."""
+    tag = Σ_t r^(TS·(T-1-t)) · P_t + s: the partials kernel sweeps a
+    (B/8, T) grid, so one launch MACs the whole batch."""
     limbs = to_limbs_batch(words)
     B, n = limbs.shape
     tile = _pick_tile(n, tile)
-    pad = (-n) % tile
-    # front-pad (zero limbs contribute 0) to keep low powers at message end
-    limbs = jnp.concatenate([jnp.zeros((B, pad), U32), limbs], axis=1)
+    # front-pad (zero limbs contribute 0) to keep low powers at message
+    # end; pad the batch to whole 8-row blocks (rows sliced off below)
+    pad_rows = (-B) % ROWS
+    limbs = jnp.pad(limbs, ((0, pad_rows), ((-n) % tile, 0)))
     T = limbs.shape[1] // tile
-    pows_tile = r_powers_batch(r, tile)                  # (B, tile)
-    partials = mac_partials_batch(limbs, pows_tile, tile=tile,
-                                  interpret=not _on_tpu())  # (B, T)
-    rTS = pows_tile[:, 0]                                # (B,) r^tile
+    r = jnp.pad(jnp.asarray(r, U32).reshape(-1), (0, pad_rows))
+    pows_tile = r_powers_batch(r, tile)                  # (Bp, tile)
+    lanes = mac_partials_batch(limbs, pows_tile, tile=tile,
+                               interpret=interpret_mode())
+    partials = _fold_lanes(lanes[:B].reshape(B, T, -1))  # (B, T)
+    rTS = pows_tile[:B, 0]                               # (B,) r^tile
 
     def step(carry, p_t):   # Horner over tiles, batched carry (B,)
         return addmod(mulmod(carry, rTS), p_t), None
@@ -66,26 +76,7 @@ def mac2_batch(words: jax.Array, r1: jax.Array, s1: jax.Array,
 
 def mac(words: jax.Array, r: jax.Array, s: jax.Array, *,
         tile: int = 4096) -> jax.Array:
-    """tag = (sum_i limb_i r^(n-i) + s) mod 2^31-1, kernel-tiled."""
-    limbs = _to_limbs(words)
-    n = limbs.shape[0]
-    pad = (-n) % tile
-    # zero limbs contribute 0 regardless of power: pad at the FRONT so the
-    # trailing (low-power) positions stay aligned with the message end.
-    limbs = jnp.concatenate([jnp.zeros((pad,), U32), limbs])
-    total = limbs.shape[0]
-    T = total // tile
-    pows_tile = r_powers(r, tile)                       # (tile,) = r^TS..r^1
-    partials = mac_partials(limbs, pows_tile, tile=tile,
-                            interpret=not _on_tpu())    # (T,)
-
-    # tile t contributes P_t * r^(TS*(T-1-t)); compute scalar factors by
-    # scanning with rTS = r^tile.
-    rTS = pows_tile[0]                                  # r^tile
-
-    def step(carry, p_t):
-        # process tiles in order: acc = acc * rTS + P_t  (Horner over tiles)
-        return addmod(mulmod(carry, rTS), p_t), None
-
-    acc, _ = jax.lax.scan(step, jnp.zeros((), U32), partials)
-    return addmod(acc, s)
+    """tag = (sum_i limb_i r^(n-i) + s) mod 2^31-1 of one (N,) message:
+    :func:`mac_batch` over a single row."""
+    return mac_batch(words.reshape(1, -1), jnp.asarray(r, U32).reshape(1),
+                     jnp.asarray(s, U32).reshape(1), tile=tile)[0]

@@ -100,9 +100,9 @@ def _enclave_rows_kernel(kin_ref, kout_ref, nonce_ref, ctr_ref,
     kin = [kin_ref[:, i] for i in range(8)]        # 8 x (rows,)
     kout = [kout_ref[:, i] for i in range(8)]
     nonce = [nonce_ref[:, i] for i in range(3)]    # 3 x (rows,)
-    counters = ctr_ref[...]                        # (rows,)
+    counters = ctr_ref[:, 0]                       # (rows,)
     nonce_out = [nonce_out_ref[:, i] for i in range(3)]
-    counters_out = ctr_out_ref[...]
+    counters_out = ctr_out_ref[:, 0]
 
     # ---- decrypt (plaintext exists only from here ...)
     ks_in = keystream_vectors(kin, nonce, counters)
@@ -132,7 +132,8 @@ def enclave_apply_rows(keys_in: jax.Array, keys_out: jax.Array,
     unless ``nonces_out``/``counters_out`` are given, in which case the
     re-encrypt uses those coordinates instead (the fault-tolerance
     replay path: a retried row must never re-spend a (key, nonce,
-    counter) triple already used on the outbound key).
+    counter) triple already used on the outbound key).  Counters enter
+    the kernel as (R, 1) columns, as in ``chacha20_xor_rows``.
     """
     R = data_rows.shape[0]
     assert R % block_rows == 0, (R, block_rows)
@@ -148,17 +149,17 @@ def enclave_apply_rows(keys_in: jax.Array, keys_out: jax.Array,
             pl.BlockSpec((block_rows, 8), lambda i: (i, 0)),
             pl.BlockSpec((block_rows, 8), lambda i: (i, 0)),
             pl.BlockSpec((block_rows, 3), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
+            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
             pl.BlockSpec((block_rows, 3), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
+            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
             pl.BlockSpec((block_rows, 16), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, 16), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(data_rows.shape, U32),
         interpret=interpret,
     )(keys_in.astype(U32), keys_out.astype(U32), nonces.astype(U32),
-      counters.astype(U32), nonces_out.astype(U32),
-      counters_out.astype(U32), data_rows)
+      counters.astype(U32).reshape(R, 1), nonces_out.astype(U32),
+      counters_out.astype(U32).reshape(R, 1), data_rows)
 
 
 def _enclave_kernel(kin_ref, kout_ref, nonce_ref, ctr_ref, data_ref, out_ref,

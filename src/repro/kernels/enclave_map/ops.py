@@ -1,9 +1,9 @@
 """Public op wrappers for the enclave executor kernels."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.enclave_map.enclave_map import (  # noqa: F401
     OPS, enclave_apply, enclave_apply_rows)
 from repro.obs.metrics import REGISTRY as _METRICS
@@ -14,17 +14,13 @@ _DISPATCHES = _METRICS.counter("device.dispatches")
 _DISP_MAP = _METRICS.counter("device.dispatches.enclave_map")
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def enclave_map(key_in, key_out, nonce, counter0, data_blocks, *, op,
                 const=0.0, block_rows: int = 512):
     _DISPATCHES.inc()
     _DISP_MAP.inc()
     return enclave_apply(key_in, key_out, nonce, counter0, data_blocks,
                          op=op, const=const, block_rows=block_rows,
-                         interpret=not _on_tpu())
+                         interpret=interpret_mode())
 
 
 def enclave_map_rows(keys_in, keys_out, nonces, counters, rows, *, op,
@@ -61,7 +57,7 @@ def enclave_map_rows(keys_in, keys_out, nonces, counters, rows, *, op,
         rows = jnp.pad(rows, ((0, pad), (0, 0)))
     out = enclave_apply_rows(kin, kout, nonces, counters, rows, op=op,
                              const=const, block_rows=block_rows,
-                             interpret=not _on_tpu(),
+                             interpret=interpret_mode(),
                              nonces_out=nonces_out,
                              counters_out=counters_out)
     return out[:R]

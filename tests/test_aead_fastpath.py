@@ -2,6 +2,8 @@
 scalar path on RFC 7539-derived vectors, Pallas-vs-jnp oracle checks,
 batched tamper detection, the shape-keyed compile cache, and the
 single-collective secure_exchange."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,6 +11,7 @@ import pytest
 
 from repro.attest.directory import ephemeral_edge_key
 from repro.crypto import aead, chacha20, cwmac
+from repro.launch.mesh import make_mesh
 
 rng = np.random.default_rng(7)
 
@@ -221,12 +224,17 @@ def test_protect_many_roundtrip_and_cross_key_rejection():
 
 def test_secure_exchange_issues_one_collective_per_round():
     from repro.dist import collectives
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     x = jax.random.normal(jax.random.key(3), (1, 1, 16, 4), jnp.float32)
     key = ephemeral_edge_key("shuffle", seed=0)
     c0 = collectives.exchange_call_count()
     y, ok = collectives.secure_exchange(x, mesh, "model", key=key, step=5)
     assert collectives.exchange_call_count() - c0 == 1
+    # the traced round itself holds exactly one all_to_all (the compiled
+    # program drops it on a 1-device axis, so count it in the jaxpr)
+    jaxpr = jax.make_jaxpr(lambda a: collectives.secure_exchange(
+        a, mesh, "model", key=key, step=5))(x)
+    assert len(re.findall(r"\ball_to_all\[", str(jaxpr))) == 1
     assert bool(ok.all())
     assert float(jnp.abs(y - jnp.swapaxes(x, 0, 1)).max()) == 0.0
 
@@ -236,7 +244,7 @@ def test_sealed_ppermute_packed_payload_roundtrip():
     from jax.sharding import PartitionSpec as P
     from repro.core.secure_channel import sealed_ppermute
     from repro.dist.compat import shard_map
-    mesh = jax.make_mesh((1,), ("stage",))
+    mesh = make_mesh((1,), ("stage",))
     key = ephemeral_edge_key("pp-edge", seed=2, stage_id=1)
     x = jnp.arange(1 * 32, dtype=jnp.uint32).reshape(1, 32)
 

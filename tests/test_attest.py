@@ -19,6 +19,7 @@ from repro.attest.quote import QuoteError, QuotePolicy
 from repro.attest.rotation import hkdf_sha256, ratchet_key
 from repro.crypto.keys import (NONCE_COUNTER_MAX, NonceExhaustedError,
                                StageKey)
+from repro.launch.mesh import make_mesh
 
 
 def _directory(seed=0, **kw):
@@ -450,7 +451,7 @@ def test_secure_exchange_with_directory_handle():
     d = _directory()
     d.establish("shuffle", "a", "b")
     h = d.handle("shuffle")
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     x = jax.random.normal(jax.random.key(3), (1, 1, 16, 4), jnp.float32)
     y, ok = collectives.secure_exchange(x, mesh, "model", key=h)  # no step
     assert bool(ok.all())

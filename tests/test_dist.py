@@ -9,10 +9,11 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ShardingConfig
 from repro.dist.meshctx import MeshContext, local_mesh_context
 from repro.launch import hloanalysis
+from repro.launch.mesh import make_mesh
 
 
 def _ctx(shape=(1, 1), axes=("data", "model")):
-    mesh = jax.make_mesh(shape, axes)
+    mesh = make_mesh(shape, axes)
     return MeshContext(mesh=mesh, rules=dict(ShardingConfig().lookup()))
 
 
@@ -86,7 +87,7 @@ def test_pp_pipeline_matches_sequential():
     """GPipe schedule over a 1-stage 'mesh' must equal direct application;
     on 1 device we can still exercise the schedule logic with S=1."""
     from repro.dist.pipeline_parallel import pipeline_apply
-    mesh = jax.make_mesh((1,), ("stage",))
+    mesh = make_mesh((1,), ("stage",))
     W = jax.random.normal(jax.random.key(0), (1, 4, 4))  # (S=1 stage, ...)
 
     def stage_fn(w, x):
@@ -170,7 +171,7 @@ def test_pp_multistage_matches_sequential(seal):
 
 def test_pp_mesh_stage_axis_validated():
     from repro.dist.pipeline_parallel import pipeline_apply
-    mesh = jax.make_mesh((1,), ("stage",))
+    mesh = make_mesh((1,), ("stage",))
     W = jnp.zeros((2, 4, 4))
     xs = jnp.zeros((3, 2, 4))
     # size-1 stage axis is fine for any S (host-driven schedule)
@@ -180,7 +181,7 @@ def test_pp_mesh_stage_axis_validated():
 def test_secure_exchange_roundtrip():
     from repro.attest.directory import ephemeral_edge_key
     from repro.dist.collectives import exchange, secure_exchange
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     W = 1
     x = jax.random.normal(jax.random.key(3), (W, W, 16, 4), jnp.float32)
     key = ephemeral_edge_key("shuffle", seed=0)

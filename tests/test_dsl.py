@@ -339,23 +339,29 @@ def test_spec_rejects_unknown_keys():
 
 
 def test_mini_toml_parser_subset():
-    from repro.dsl.spec import parse_toml
-    doc = parse_toml("""
+    """The TOML subset specs use — comments, both quote styles, ints,
+    floats, tables and arrays of tables — loads through ``load_spec``."""
+    sb = load_spec("""
     # comment
     name = "x"            # trailing comment
-    n = 3
-    f = 1.5
-    flag = true
-    [a.b]
-    k = 'single'
-    [[arr]]
-    v = 1
-    [[arr]]
-    v = 2
+    window_chunks = 3
+    [pipeline]
+    mode = 'plain'
+    [[stage]]
+    name = "f"
+    op = 'delay_filter_u32'
+    const = 1.5
+    count = 2
+    [[stage]]
+    name = "r"
+    reduce = "carrier_delay_stats"
     """)
-    assert doc["name"] == "x" and doc["n"] == 3 and doc["f"] == 1.5
-    assert doc["flag"] is True and doc["a"]["b"]["k"] == "single"
-    assert [t["v"] for t in doc["arr"]] == [1, 2]
+    p = sb.build()
+    assert p.secure.mode == "plain" and p.window_chunks == 3
+    assert [s.name for s in p.stages] == ["f", "r"]
+    assert p.stages[0].const == 1.5 and p.stages[0].workers == 2
+    with pytest.raises(SpecError, match="unknown top-level key 'flag'"):
+        load_spec("flag = true\n[[stage]]\nname = 'f'\nop = 'identity'\n")
 
 
 def test_registered_reducer_roundtrip():

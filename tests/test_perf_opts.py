@@ -8,6 +8,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_run_config
 from repro.dist.meshctx import MeshContext
+from repro.launch.mesh import make_mesh
 from repro.models.flash import flash_attention
 from repro.models.hier_attn import hier_causal_attention
 
@@ -61,7 +62,7 @@ def test_fsdp_expert_weight_specs():
     from repro.models.moe import moe_template
     from repro.models.layers import shardings_from_template
     run = get_run_config("kimi-k2-1t-a32b", "train_4k")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ctx = MeshContext(mesh=mesh, rules=run.sharding.lookup())
     sh = shardings_from_template(moe_template(run.model), ctx)
     assert sh["wg"].spec == P("model", None, "data")
