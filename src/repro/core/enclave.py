@@ -110,7 +110,10 @@ class SealedWindow:
     eager dispatch per row per hop), only gathered at worker fan-out and
     materialized at the sink.  ``counters``/``epochs`` are host-side
     per-row metadata; a window straddling a rekey flip simply carries
-    mixed ``epochs`` and is opened with per-row keys.
+    mixed ``epochs`` and is opened with per-row keys.  ``window_id`` is
+    the ingress window's sequence number in its ``run()``, host-side
+    metadata that the engine's trace spans carry (``window=``); it
+    survives ``select``, the merge and egress.
     """
     words: jax.Array              # (B, n_words) u32 payload rows (ct, or
                                   # plaintext words in plain mode)
@@ -119,6 +122,7 @@ class SealedWindow:
     epochs: List[int]             # per-row ingress epochs
     meta: Tuple                   # shared tensor framing (shape, dtype, pad)
     n_words: int
+    window_id: int = 0            # ingress window sequence number
 
     def __len__(self) -> int:
         return len(self.counters)
@@ -131,7 +135,7 @@ class SealedWindow:
             tags=None if self.tags is None else self.tags[idx],
             counters=[self.counters[i] for i in idxs],
             epochs=[self.epochs[i] for i in idxs],
-            meta=self.meta, n_words=self.n_words)
+            meta=self.meta, n_words=self.n_words, window_id=self.window_id)
 
 
 def _blocks_batch(words: jax.Array) -> jax.Array:
@@ -445,16 +449,19 @@ class EnclaveExecutor:
                 "attested — the paper's no-dynamic-linking rule.")
         out_view, out_ctrs, out_epochs = _reseal_coords(win, reseal_as)
         with self.tracer.span("enclave.open", cat="dispatch",
-                              track=self.track, rows=len(win)):
+                              track=self.track, rows=len(win),
+                              window=win.window_id):
             keys_in, nonces_in = _window_cipher_params(self.key_in, win)
             pt, ok = aead.open_many(keys_in, nonces_in, win.words, win.tags)
         with self.tracer.span("enclave.op", cat="dispatch",
-                              track=self.track, rows=len(win)):
+                              track=self.track, rows=len(win),
+                              window=win.window_id):
             xb = aead.words_to_tensor_batch(pt, win.meta)
             yb = jnp.stack([fn(xb[b]) for b in range(len(win))])
             words, meta = aead.tensor_to_words_batch(yb)
         with self.tracer.span("enclave.seal", cat="dispatch",
-                              track=self.track, rows=len(win)):
+                              track=self.track, rows=len(win),
+                              window=win.window_id):
             keys_out, nonces_out = _window_cipher_params(self.key_out,
                                                          out_view)
             ct, tags = aead.seal_many(keys_out, nonces_out, words)
@@ -487,14 +494,17 @@ class EnclaveExecutor:
         keys_out, nonces_out = _window_cipher_params(self.key_out, out_view)
         if self.mode == "encrypted":
             with self.tracer.span("enclave.open", cat="dispatch",
-                                  track=self.track, rows=len(win)):
+                                  track=self.track, rows=len(win),
+                                  window=win.window_id):
                 pt, ok = aead.open_many(keys_in, nonces_in,
                                         win.words, win.tags)
             with self.tracer.span("enclave.op", cat="dispatch",
-                                  track=self.track, op=op, rows=len(win)):
+                                  track=self.track, op=op, rows=len(win),
+                                  window=win.window_id):
                 words = _apply_static_words(op, const, pt)
             with self.tracer.span("enclave.seal", cat="dispatch",
-                                  track=self.track, rows=len(win)):
+                                  track=self.track, rows=len(win),
+                                  window=win.window_id):
                 ct, tags = aead.seal_many(keys_out, nonces_out, words)
             return replace(win, words=ct, tags=tags, counters=out_ctrs,
                            epochs=out_epochs), ok
@@ -503,14 +513,15 @@ class EnclaveExecutor:
         B, n_words = len(win), win.n_words
         n_blocks = (n_words + 15) // 16
         with self.tracer.span("enclave.open", cat="dispatch",
-                              track=self.track, rows=B):
+                              track=self.track, rows=B, window=win.window_id):
             mk_in = aead.derive_mac_keys_many(keys_in, nonces_in)
             ok = jnp.all(aead.mac2_many(win.words, mk_in) == win.tags,
                          axis=-1)
         # fused decrypt->op->encrypt over the window's flattened rows;
         # payload keystream offset is counter0=1 per chunk.
         with self.tracer.span("enclave.op", cat="dispatch",
-                              track=self.track, op=op, rows=B):
+                              track=self.track, op=op, rows=B,
+                              window=win.window_id):
             rows = _blocks_batch(win.words).reshape(-1, 16)
             row_nonces = jnp.repeat(nonces_in, n_blocks, axis=0)
             row_ctrs = jnp.tile(jnp.arange(1, n_blocks + 1, dtype=U32), B)
@@ -529,7 +540,7 @@ class EnclaveExecutor:
                 const=const, **kw).reshape(B, -1)[:, :n_words]
         # re-tag under the outbound keys, batched
         with self.tracer.span("enclave.seal", cat="dispatch",
-                              track=self.track, rows=B):
+                              track=self.track, rows=B, window=win.window_id):
             mk_out = aead.derive_mac_keys_many(keys_out, nonces_out)
             tags_out = aead.mac2_many(out_words, mk_out)
         return replace(win, words=out_words, tags=tags_out,

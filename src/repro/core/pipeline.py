@@ -16,8 +16,9 @@ directory.  Each stage dispatches every worker's per-window queue as ONE
 batched open -> operator -> seal program chain
 (:meth:`repro.core.enclave.EnclaveExecutor.run_static_many`), and MAC
 verdicts are **deferred**: per-row verdicts stay on device and sync to
-host once per window — failed rows are dropped there and counted as
-``mac_failures`` — instead of one blocking ``bool()`` per chunk.
+host once per hop per window (each stage, then the sink) — failed rows
+are dropped there and counted as ``mac_failures`` — instead of one
+blocking ``bool()`` per chunk.
 ``window_chunks=1`` degenerates to the original per-chunk engine and is
 kept as the bit-identical oracle.  Batched programs live in the AEAD
 shape-keyed compile cache, so steady-state streaming compiles nothing.
@@ -57,6 +58,7 @@ from repro.core import router as R
 from repro.core.enclave import (EnclaveExecutor, SealedChunk, SealedWindow,
                                 egress, egress_window, ingress, plain_window,
                                 seal_tensors_window, uniform_runs)
+from repro.obs.host import to_host
 from repro.obs.metrics import (REGISTRY as _METRICS, dispatch_count,
                                reset_dispatch_count)  # noqa: F401 (re-export)
 from repro.obs.monitor import NULL_MONITOR
@@ -137,9 +139,12 @@ class StageMetrics:
         return self.mac_failures / seen
 
 
-# One host rendezvous per window (deferred-verdict sync + block on the
-# window's outputs).  A regression back to per-chunk syncing shows up as
-# this counter growing with the chunk count instead of the window count.
+# One host rendezvous per hop per window (deferred-verdict sync + block on
+# the hop's outputs): a two-stage job makes three per window, one per
+# stage and one at the sink.  A regression back to per-chunk syncing
+# shows up as this counter growing with the chunk count instead of the
+# window count.  The transfers themselves, the reducer's included, are
+# counted apart (``device.to_host``, repro.obs.host).
 # Registered in the process-wide metrics registry; the module-level
 # functions below are the original API, kept as thin shims.
 _HOST_SYNCS = _METRICS.counter("pipeline.host_syncs")
@@ -147,8 +152,8 @@ _HOST_SYNCS = _METRICS.counter("pipeline.host_syncs")
 
 def host_sync_count() -> int:
     """Device->host synchronisation rendezvous performed by the streaming
-    engine (one per window).  Tests assert one sync per window.  Shim
-    over the registered counter ``pipeline.host_syncs``."""
+    engine (one per hop per window).  Shim over the registered counter
+    ``pipeline.host_syncs``."""
     return int(_HOST_SYNCS.value)
 
 
@@ -174,16 +179,18 @@ def _shape_runs(xs: List[jax.Array]):
 
 def _sync_window(outputs: List[jax.Array],
                  vec_specs: List[Tuple[Optional[jax.Array], int]],
-                 tracer=NULL_TRACER, track: str = "main") -> np.ndarray:
-    """THE one host sync of a window: block until the window's outputs are
-    ready and materialize every deferred MAC verdict in a single
-    transfer.  ``vec_specs`` is [(device verdict vector or None, n)];
-    None (plain mode) counts as all-pass.  The ``sync.verdicts`` span is
-    where device time surfaces on a timeline — dispatch spans upstream
-    only measure (async) enqueue."""
+                 tracer=NULL_TRACER, track: str = "main",
+                 window: int = 0) -> np.ndarray:
+    """One hop's host sync of a window: block until the hop's outputs are
+    ready and materialize every deferred MAC verdict in a single counted
+    transfer (:func:`repro.obs.host.to_host`).  ``vec_specs`` is [(device
+    verdict vector or None, n)]; None (plain mode) counts as all-pass and
+    moves nothing.  The ``sync.verdicts`` span is where device time
+    surfaces on a timeline — dispatch spans upstream only measure (async)
+    enqueue.  ``window`` is the first window's id, for the span."""
     _HOST_SYNCS.inc()
     with tracer.span("sync.verdicts", cat="sync", track=track,
-                     rows=sum(n for _, n in vec_specs)):
+                     rows=sum(n for _, n in vec_specs), window=window):
         if outputs:
             jax.block_until_ready(outputs)
         if all(ok is None for ok, _ in vec_specs):
@@ -191,7 +198,7 @@ def _sync_window(outputs: List[jax.Array],
         parts = [jnp.ones((n,), bool) if ok is None else ok
                  for ok, n in vec_specs]
         vec = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-        return np.asarray(vec)
+        return to_host(vec)
 
 
 class Pipeline:
@@ -388,15 +395,15 @@ class Pipeline:
             if not parts:
                 return
             depth.set(got)
-            tr.counter("queue_rows", got, track=st.name)
             # pulling the window may itself have revoked workers upstream
             live = self._live_workers(st)
             L = len(live)
             d0 = _DISPATCHES.value
             t0 = time.perf_counter()
             dispatches = []          # (part idx, worker, row idxs, out, ok)
+            wid = parts[0].window_id
             with tr.span("stage.dispatch", cat="dispatch", track=st.name,
-                         rows=got, workers=L):
+                         rows=got, workers=L, window=wid):
                 for pi, win in enumerate(parts):
                     B = len(win)
                     assign = [(phase + j) % L for j in range(B)]
@@ -416,7 +423,7 @@ class Pipeline:
             verdicts = _sync_window(
                 [d[3].words for d in dispatches],
                 [(d[4], len(d[3])) for d in dispatches],
-                tracer=tr, track=st.name)
+                tracer=tr, track=st.name, window=wid)
             # honest window timing: t0 -> after block_until_ready, so
             # throughput_mbps reflects execution, not async enqueue
             dt = time.perf_counter() - t0
@@ -425,8 +432,6 @@ class Pipeline:
             m.windows += 1
             disp = _DISPATCHES.value - d0
             m.dispatches += disp
-            tr.counter("windows_per_s", (1.0 / dt) if dt > 0 else 0.0,
-                       track=st.name)
             off = 0
             marks: List[np.ndarray] = []
             for pi, w, idxs, out, _ in dispatches:
@@ -457,7 +462,7 @@ class Pipeline:
                     min_epoch=min(min(p.epochs) for p in parts),
                     dispatches=disp)
             with tr.span("stage.merge", cat="pipeline", track=st.name,
-                         windows=len(parts)):
+                         windows=len(parts), window=wid):
                 merged = list(self._merge_outputs(parts, dispatches, marks))
             yield from merged
 
@@ -498,7 +503,8 @@ class Pipeline:
                 tags=None if cat_t is None else cat_t[sel],
                 counters=[e[2] for e in entries],
                 epochs=[e[3] for e in entries],
-                meta=outs[0].meta, n_words=outs[0].n_words)
+                meta=outs[0].meta, n_words=outs[0].n_words,
+                window_id=parts[pi].window_id)
 
     # ------------------------------------------------------ fault tolerance
 
@@ -781,7 +787,6 @@ class Pipeline:
             # blocks) until this round's verdict sync is folded in
             ft.buffer.retain(st.name, rnd, parts)
             depth.set(got)
-            tr.counter("queue_rows", got, track=st.name)
             live = [w for w in self._live_workers(st)
                     if not ft.is_dead(st.name, w)]
             L = len(live)
@@ -789,8 +794,9 @@ class Pipeline:
             t0 = time.perf_counter()
             dispatches = []          # (part idx, worker, row idxs, out, ok)
             flags = []               # aligned: per-share fault markers
+            wid = parts[0].window_id
             with tr.span("stage.dispatch", cat="dispatch", track=st.name,
-                         rows=got, workers=L):
+                         rows=got, workers=L, window=wid):
                 for pi, win in enumerate(parts):
                     B = len(win)
                     assign = [(phase + j) % L for j in range(B)]
@@ -822,15 +828,13 @@ class Pipeline:
             verdicts = _sync_window(
                 [d[3].words for d in dispatches],
                 [(d[4], len(d[3])) for d in dispatches],
-                tracer=tr, track=st.name)
+                tracer=tr, track=st.name, window=wid)
             dt = time.perf_counter() - t0
             m.seconds += dt
             lat.observe(dt)
             m.windows += 1
             disp = _DISPATCHES.value - d0
             m.dispatches += disp
-            tr.counter("windows_per_s", (1.0 / dt) if dt > 0 else 0.0,
-                       track=st.name)
             # ---- per-row accounting + replay scheduling
             off = 0
             final = []               # dispatch tuples fed to the merge
@@ -876,7 +880,7 @@ class Pipeline:
                                  round=rnd)
                 rv = _sync_window([d[3].words for d in rd],
                                   [(d[4], len(d[3])) for d in rd],
-                                  tracer=tr, track=st.name)
+                                  tracer=tr, track=st.name, window=wid)
                 roff = 0
                 for (pi, _, row_js, reason), (pi2, wr, _, out2, _) \
                         in zip(replays, rd):
@@ -912,7 +916,7 @@ class Pipeline:
                     min_epoch=min(min(p.epochs) for p in parts),
                     dispatches=disp)
             with tr.span("stage.merge", cat="pipeline", track=st.name,
-                         windows=len(parts)):
+                         windows=len(parts), window=wid):
                 merged = list(self._merge_outputs(parts, final, marks))
             # the round's verdicts are folded in: release retained rows
             ft.buffer.ack(st.name, rnd)
@@ -939,6 +943,11 @@ class Pipeline:
         per-chunk engine would have fired it — so rotation resets the
         managed counter, counters stay epoch-local, and chunks sealed just
         before a flip carry their epoch and drain under the old key.
+
+        Windows are numbered 0, 1, 2, ... per call: every
+        :class:`SealedWindow` of a window carries its ``window_id``, and
+        the ``ingress.fill`` span (pulling its chunks from the source)
+        and ``ingress.seal`` span carry it as ``window=``.
         """
         it = iter(source)
         n_plain = 0
@@ -946,14 +955,17 @@ class Pipeline:
         mon = self.monitor
         buffered = _METRICS.gauge("pipeline.ingress.buffered_rows")
         prev: Optional[List[SealedWindow]] = None
-        while True:
+        for wid in itertools.count():
+            t_fill = tr.now()
             xs = list(itertools.islice(it, window))
             if not xs:
                 break
+            tr.complete("ingress.fill", t_fill, cat="source",
+                        track="ingress", rows=len(xs), window=wid)
             d0 = _DISPATCHES.value
             t0 = time.perf_counter()
             with tr.span("ingress.seal", cat="dispatch", track="ingress",
-                         rows=len(xs)):
+                         rows=len(xs), window=wid):
                 if mode == "plain":
                     cur = [plain_window(range(n_plain + j,
                                               n_plain + j + len(sub)), sub)
@@ -961,6 +973,8 @@ class Pipeline:
                     n_plain += len(xs)
                 else:
                     cur = self._seal_ingress_window(xs, rekey_every_n)
+            for w in cur:
+                w.window_id = wid
             buffered.set(len(xs))
             disp = _DISPATCHES.value - d0
             self._ingress_windows_n += 1
@@ -1166,14 +1180,16 @@ class Pipeline:
             m = self.metrics[st.name]
             audit = self.directory.audit
             egress_lat = _METRICS.histogram("pipeline.egress.window_seconds")
+            tr = self.tracer
             reduce_state: Any = None
             reduce_started = False
             for groups, verdicts, dt in self._egress_windows(
                     stream, mode, self.keys[reduce_idx], egress_rows):
                 egress_lat.observe(dt)
                 t0 = time.perf_counter()
-                with self.tracer.span("reduce.fold", cat="pipeline",
-                                      track="sink", rows=len(verdicts)):
+                with tr.span("reduce.fold", cat="pipeline", track="sink",
+                             rows=len(verdicts),
+                             window=groups[0][0].window_id):
                     off = 0
                     for win, vals in groups:
                         for j in range(len(win)):
@@ -1188,8 +1204,11 @@ class Pipeline:
                             if not reduce_started:
                                 reduce_state = st.reduce_init
                                 reduce_started = True
-                            reduce_state = st.reduce_fn(reduce_state,
-                                                        vals[j])
+                            x = vals[j]
+                            with tr.span("reduce.fn", cat="pipeline",
+                                         track="sink",
+                                         window=win.window_id):
+                                reduce_state = st.reduce_fn(reduce_state, x)
                             m.chunks += 1
                             m.bytes += int(win.n_words) * 4
                         off += len(win)
@@ -1239,14 +1258,16 @@ class Pipeline:
         t0 = time.perf_counter()
         groups = []
         specs = []
+        wid = parts[0].window_id
         with self.tracer.span("egress.open", cat="dispatch", track="sink",
-                              rows=sum(len(w) for w in parts)):
+                              rows=sum(len(w) for w in parts), window=wid):
             for win in parts:
                 vals, ok = egress_window(mode, key, win)
                 groups.append((win, vals))
                 specs.append((ok, len(win)))
         verdicts = _sync_window([v for _, v in groups], specs,
-                                tracer=self.tracer, track="sink")
+                                tracer=self.tracer, track="sink",
+                                window=wid)
         dt = time.perf_counter() - t0
         disp = _DISPATCHES.value - d0
         self._egress_windows_n += 1

@@ -6,7 +6,8 @@ spec says ``reduce = "carrier_delay_stats"``.  Each registration is a
 *factory* returning a fresh ``(fn, init)`` pair per pipeline build (a
 shared mutable ``init`` across builds would make reruns accumulate).
 
-Built-ins:
+Built-ins (their device->host transfers are counted, see
+:func:`repro.obs.host.to_host`):
 
 * ``carrier_delay_stats`` — the paper's own DelayedFlights benchmark
   (§5.2): per-carrier delayed-flight counts + delay sums over packed
@@ -31,6 +32,7 @@ from typing import Any, Callable, Dict, Tuple
 import numpy as np
 
 from repro.data.synthetic import CARRIER_WORD, DELAY_WORD
+from repro.obs.host import to_host
 
 ReducerFactory = Callable[..., Tuple[Callable, Any]]
 
@@ -61,8 +63,8 @@ def resolve_reducer(name: str, **kw) -> Tuple[Callable, Any]:
 def _carrier_delay_stats(num_carriers: int = 20):
     """Per-carrier delayed count + delay-minute sum (paper §5.2)."""
     def fn(acc, chunk):
-        carrier = np.asarray(chunk[:, CARRIER_WORD]).astype(np.int64)
-        delay = np.asarray(chunk[:, DELAY_WORD]).astype(np.int64)
+        carrier = to_host(chunk[:, CARRIER_WORD]).astype(np.int64)
+        delay = to_host(chunk[:, DELAY_WORD]).astype(np.int64)
         valid = delay > 0
         acc["count"] = acc["count"] + np.bincount(
             carrier[valid], minlength=num_carriers)
@@ -77,7 +79,7 @@ def _carrier_delay_stats(num_carriers: int = 20):
 def _sum():
     """Elementwise running sum over chunks (None-seeded first fold)."""
     def fn(acc, chunk):
-        return chunk if acc is None else acc + np.asarray(chunk)
+        return chunk if acc is None else acc + to_host(chunk)
     return fn, None
 
 

@@ -15,14 +15,21 @@ Tracing is **off by default and zero-cost when disabled**: code holds
 :data:`NULL_TRACER` (a :class:`NullTracer`) unless a real tracer is
 passed in, and its ``span()``/``instant()`` are no-ops returning one
 shared reusable context manager — no span objects, no clock reads, no
-list growth.  The pipeline bench (``pipeline.traced`` row) enforces the
-<= 2% enabled / parity disabled budget.
+list growth.  What tracing costs when it is on is measured on the chip,
+with the benchmark's saturated cells (PERF.md).
+
+Every window-engine span carries ``window=<id>``, the window's ingress
+sequence number in its ``run()``, so one window can be followed from the
+source (``ingress.fill``) to the sink's fold (``reduce.fold``, and one
+``reduce.fn`` per chunk around the job's reducer); a span that covers
+several windows carries the first one's id.
 
 A deliberate caveat: spans around *asynchronously dispatched* device
 work (category ``"dispatch"``) measure enqueue time, not execution —
-execution lands in the per-window ``sync.verdicts`` span, which brackets
-the engine's single ``block_until_ready`` per window.  The span args
-carry that distinction so the timeline stays honest.
+execution lands in the ``sync.verdicts`` spans, each of which brackets
+one hop's ``block_until_ready`` (one per stage and one at the sink per
+window).  The span args carry that distinction so the timeline stays
+honest.
 """
 from __future__ import annotations
 
@@ -83,6 +90,13 @@ class NullTracer:
         return None
 
     def counter(self, name: str, value: float, track: str = "main") -> None:
+        return None
+
+    def now(self) -> float:
+        return 0.0
+
+    def complete(self, name: str, start: float, cat: str = "pipeline",
+                 track: str = "main", **args) -> None:
         return None
 
 
@@ -154,6 +168,23 @@ class Tracer:
         t = self._clock() - self._t0
         s = Span(id=len(self.spans), name=name, cat=cat, track=track,
                  start=t, end=t,
+                 parent=self._stack[-1] if self._stack else None,
+                 args=args)
+        self.spans.append(s)
+        return s
+
+    def now(self) -> float:
+        """The tracer's clock, for a later :meth:`complete`."""
+        return self._clock()
+
+    def complete(self, name: str, start: float, cat: str = "pipeline",
+                 track: str = "main", **args) -> Span:
+        """Record a span that began at ``start`` (a :meth:`now` reading)
+        and ends now — for an interval that is kept only once its
+        outcome is known (e.g. a pull from the source that found
+        data)."""
+        s = Span(id=len(self.spans), name=name, cat=cat, track=track,
+                 start=start - self._t0, end=self._clock() - self._t0,
                  parent=self._stack[-1] if self._stack else None,
                  args=args)
         self.spans.append(s)

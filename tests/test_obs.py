@@ -482,3 +482,144 @@ def test_chunked_oracle_engine_is_traced_and_audited(monkeypatch):
     assert len(tr.find("stage.chunk")) == 3
     assert tr.find("pipeline.run")
     assert d.audit.counts()["mac_failure"] == 0
+
+
+# ------------------------------------------- window ids and host transfers
+
+# every window-engine span and the window id it carries
+_WINDOW_SPANS = ("ingress.fill", "ingress.seal", "stage.dispatch",
+                 "enclave.open", "enclave.op", "enclave.seal",
+                 "sync.verdicts", "stage.merge", "egress.open",
+                 "reduce.fold", "reduce.fn")
+_HOPS = ("mapper", "filter")
+_N_CHUNKS = 20                     # two 8-chunk windows and a ragged tail
+_TRANSFERS = ("device.to_host", "device.to_host_bytes",
+              "device.to_host_seconds", "pipeline.host_syncs")
+
+
+@pytest.fixture(scope="module")
+def flights_runs():
+    """One traced run of a small DelayedFlights job per mode: map,
+    filter (2 workers each) and the carrier_delay_stats fold, with the
+    transfer counters read over the run."""
+    from repro.data.synthetic import flight_chunks
+    from repro.dsl import stream
+    chunks = list(flight_chunks(16 * _N_CHUNKS, 16, seed=3))
+    out = {}
+    for mode in ("plain", "encrypted", "enclave"):
+        p = (stream().map("identity", name=_HOPS[0], workers=2)
+             .filter("delay_filter_u32", const=15, name=_HOPS[1],
+                     workers=2)
+             .reduce("carrier_delay_stats", name="sink").window(4)
+             .seed(0).build(mode))
+        tr = Tracer()
+        c0 = {k: REGISTRY.counter(k).value for k in _TRANSFERS}
+        res = p.run((jnp.asarray(c) for c in chunks), tracer=tr)
+        out[mode] = (tr, {k: REGISTRY.counter(k).value - c0[k]
+                          for k in _TRANSFERS}, res)
+    return out
+
+
+@pytest.mark.parametrize("mode", ["plain", "encrypted", "enclave"])
+def test_every_window_engine_span_carries_its_window(flights_runs, mode):
+    tr, _, _ = flights_runs[mode]
+    names = {s.name for s in tr.spans} - {"pipeline.run"}
+    want = set(_WINDOW_SPANS)
+    if mode == "plain":               # the plain executor has no phases
+        want -= {"enclave.open", "enclave.op", "enclave.seal"}
+    assert names == want
+    for name in want:
+        ids = {s.args["window"] for s in tr.find(name)}
+        assert ids == {0, 1, 2}, name          # 0..n-1, no gaps
+    # one fill and one seal per window, siblings under pipeline.run
+    fills, seals = tr.find("ingress.fill"), tr.find("ingress.seal")
+    assert [s.args["window"] for s in fills] == [0, 1, 2]
+    assert [s.args["rows"] for s in fills] == [8, 8, 4]
+    run = tr.find("pipeline.run")[0]
+    assert {s.parent for s in fills + seals} == {run.id}
+    # the engine samples no per-window tracer counters
+    assert tr.counters == []
+
+
+@pytest.mark.parametrize("mode", ["plain", "encrypted", "enclave"])
+def test_one_window_is_followed_from_source_to_fold(flights_runs, mode):
+    tr, _, _ = flights_runs[mode]
+
+    def of(name, w, track=None):
+        return [s for s in tr.find(name) if s.args["window"] == w
+                and track in (None, s.track)]
+
+    for w in (0, 1, 2):
+        fill, = of("ingress.fill", w)
+        seal, = of("ingress.seal", w)
+        assert fill.end <= seal.start
+        t = seal.end
+        for hop in _HOPS:
+            disp, = of("stage.dispatch", w, hop)
+            sync, = of("sync.verdicts", w, hop)
+            merge, = of("stage.merge", w, hop)
+            assert t <= disp.start <= disp.end <= sync.start <= sync.end \
+                <= merge.start
+            t = merge.end
+        opened, = of("egress.open", w)
+        sync, = of("sync.verdicts", w, "sink")
+        fold, = of("reduce.fold", w)
+        assert t <= opened.start <= sync.start <= fold.start
+        fns = of("reduce.fn", w)
+        assert len(fns) == fold.args["rows"] == (4 if w == 2 else 8)
+        assert {s.parent for s in fns} == {fold.id}
+    # the double buffer: window 0 is handed on only once window 1 is sealed
+    assert of("ingress.seal", 1)[0].end \
+        <= of("stage.dispatch", 0, _HOPS[0])[0].start
+    assert len(tr.find("reduce.fn")) == _N_CHUNKS
+
+
+@pytest.mark.parametrize("mode", ["plain", "encrypted", "enclave"])
+def test_to_host_counts_the_reducer_and_the_verdict_syncs(flights_runs,
+                                                          mode):
+    """carrier_delay_stats brings two columns of every chunk to the host;
+    each hop's and the sink's verdict sync brings one vector per window
+    in the sealed modes, and none in plain mode, where verdicts never
+    leave the host."""
+    _, got, res = flights_runs[mode]
+    syncs = got["pipeline.host_syncs"]
+    assert syncs == 3 * 3                       # 3 windows x (2 hops + sink)
+    verdicts = 0 if mode == "plain" else syncs
+    assert got["device.to_host"] == 2 * _N_CHUNKS + verdicts
+    # two 16-row uint32 columns per chunk; one bool per verdict row
+    assert got["device.to_host_bytes"] == \
+        2 * _N_CHUNKS * 16 * 4 + (0 if mode == "plain" else 3 * _N_CHUNKS)
+    assert got["device.to_host_seconds"] > 0
+    assert res["count"].sum() > 0
+
+
+def test_to_host_equals_np_asarray_and_counts_device_arrays():
+    from repro.obs import to_host
+    n = REGISTRY.counter("device.to_host")
+    nbytes = REGISTRY.counter("device.to_host_bytes")
+    xs = [jnp.arange(12, dtype=jnp.uint32).reshape(3, 4),
+          jnp.asarray([True, False, True]),
+          jnp.linspace(0.0, 1.0, 5, dtype=jnp.float32)]
+    n0, b0 = n.value, nbytes.value
+    for x in xs:
+        got, want = to_host(x), np.asarray(x)
+        assert isinstance(got, np.ndarray)
+        assert got.dtype == want.dtype and np.array_equal(got, want)
+    assert n.value - n0 == 3
+    assert nbytes.value - b0 == 12 * 4 + 3 + 5 * 4
+    host = np.ones(4)
+    assert to_host(host) is host                # nothing moves: uncounted
+    assert n.value - n0 == 3
+
+
+def test_tracer_complete_records_a_span_that_ended_now():
+    tr = Tracer()
+    with tr.span("outer"):
+        t = tr.now()
+        s = tr.complete("pull", t, cat="source", track="ingress", window=4)
+    outer = tr.find("outer")[0]
+    assert s.parent == outer.id and s.track == "ingress"
+    assert outer.start <= s.start <= s.end <= outer.end
+    assert s.args == {"window": 4}
+    assert NULL_TRACER.now() == 0.0
+    assert NULL_TRACER.complete("pull", 0.0, window=1) is None
