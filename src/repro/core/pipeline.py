@@ -77,7 +77,12 @@ class Stage:
     ``constraint:type==sgx`` placement flag (non-sgx stages run on the
     encrypted, non-enclave path when the pipeline mode is ``enclave``).
     A stage with ``reduce_fn`` is terminal: it folds decrypted chunks at
-    the trusted sink edge, seeded with ``reduce_init``.
+    the trusted sink edge, seeded with ``reduce_init``.  ``reduce_fn(acc,
+    chunk)`` is called once per verified chunk, in stream order; on the
+    window engine ``chunk`` is a read-only host (NumPy) array of the
+    chunk's shape and dtype, a row of its egress window brought to the
+    host in one transfer (the ``window_chunks=1`` oracle passes the
+    device array).
 
     Stages are usually not built by hand anymore — ``repro.dsl.stream``
     / ``repro.dsl.load_spec`` compile to this dataclass (bit-identically;
@@ -143,8 +148,9 @@ class StageMetrics:
 # the hop's outputs): a two-stage job makes three per window, one per
 # stage and one at the sink.  A regression back to per-chunk syncing
 # shows up as this counter growing with the chunk count instead of the
-# window count.  The transfers themselves, the reducer's included, are
-# counted apart (``device.to_host``, repro.obs.host).
+# window count.  The transfers themselves (each verdict vector, each
+# opened egress group) are counted apart (``device.to_host``,
+# repro.obs.host).
 # Registered in the process-wide metrics registry; the module-level
 # functions below are the original API, kept as thin shims.
 _HOST_SYNCS = _METRICS.counter("pipeline.host_syncs")
@@ -199,6 +205,21 @@ def _sync_window(outputs: List[jax.Array],
                  for ok, n in vec_specs]
         vec = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
         return to_host(vec)
+
+
+def _verified_rows_to_host(vals: jax.Array,
+                           ok: np.ndarray) -> Optional[np.ndarray]:
+    """The rows of an opened egress group whose MAC verified, in stream
+    order, brought to the host in one counted transfer
+    (:func:`repro.obs.host.to_host`).  A failed row never leaves the
+    device: a group with any failure is first gathered down to its
+    verified rows there.  None when no row verified (nothing moves)."""
+    if ok.all():
+        return to_host(vals)
+    keep = np.flatnonzero(ok)
+    if keep.size == 0:
+        return None
+    return to_host(jnp.take(vals, keep, axis=0))
 
 
 class Pipeline:
@@ -1192,8 +1213,11 @@ class Pipeline:
                              window=groups[0][0].window_id):
                     off = 0
                     for win, vals in groups:
+                        ok = verdicts[off:off + len(win)]
+                        host = _verified_rows_to_host(vals, ok)
+                        k = 0                 # next verified row of `host`
                         for j in range(len(win)):
-                            if not verdicts[off + j]:
+                            if not ok[j]:
                                 m.mac_failures += 1
                                 audit.record(
                                     "mac_failure", stage=st.name,
@@ -1204,7 +1228,8 @@ class Pipeline:
                             if not reduce_started:
                                 reduce_state = st.reduce_init
                                 reduce_started = True
-                            x = vals[j]
+                            x = host[k]
+                            k += 1
                             with tr.span("reduce.fn", cat="pipeline",
                                          track="sink",
                                          window=win.window_id):

@@ -6,8 +6,16 @@ spec says ``reduce = "carrier_delay_stats"``.  Each registration is a
 *factory* returning a fresh ``(fn, init)`` pair per pipeline build (a
 shared mutable ``init`` across builds would make reruns accumulate).
 
-Built-ins (their device->host transfers are counted, see
-:func:`repro.obs.host.to_host`):
+A reducer is ``fn(acc, chunk) -> acc``, called once per verified chunk
+in stream order.  On the window engine ``chunk`` is a read-only host
+(NumPy) array of the chunk's shape and dtype: the sink brings each opened
+egress window to the host in one counted transfer
+(:func:`repro.obs.host.to_host`) and hands the reducer its rows.  The
+per-chunk oracle engine (``window_chunks=1``) passes the device array;
+the built-ins call ``to_host`` on what they read, which passes NumPy
+input through uncounted, so they fold either.
+
+Built-ins:
 
 * ``carrier_delay_stats`` — the paper's own DelayedFlights benchmark
   (§5.2): per-carrier delayed-flight counts + delay sums over packed

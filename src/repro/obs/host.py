@@ -10,12 +10,12 @@ always-on registry counters:
   the array depends on.
 
 Every blocking materialisation on the window engine's hot path goes
-through it (the verdict vector of each window sync, the built-in
-reducers), so a change that batches or removes transfers shows in these
-counters where the transfers happen.  They are kept apart from
-``pipeline.host_syncs`` (engine rendezvous) and ``device.dispatches``
-(compiled-program launches).  A NumPy array passes through uncounted:
-nothing moves.
+through it (the verdict vector of each window sync, each opened egress
+group the sink hands to the reducer), so a change that batches or
+removes transfers shows in these counters where the transfers happen.
+They are kept apart from ``pipeline.host_syncs`` (engine rendezvous) and
+``device.dispatches`` (compiled-program launches).  A NumPy array passes
+through uncounted: nothing moves.
 """
 from __future__ import annotations
 

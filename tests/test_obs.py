@@ -577,20 +577,171 @@ def test_one_window_is_followed_from_source_to_fold(flights_runs, mode):
 @pytest.mark.parametrize("mode", ["plain", "encrypted", "enclave"])
 def test_to_host_counts_the_reducer_and_the_verdict_syncs(flights_runs,
                                                           mode):
-    """carrier_delay_stats brings two columns of every chunk to the host;
-    each hop's and the sink's verdict sync brings one vector per window
-    in the sealed modes, and none in plain mode, where verdicts never
-    leave the host."""
+    """The sink brings each window's opened rows to the host in one
+    transfer, and the reducer brings nothing more; each hop's and the
+    sink's verdict sync brings one vector per window in the sealed modes,
+    and none in plain mode, where verdicts never leave the host."""
     _, got, res = flights_runs[mode]
     syncs = got["pipeline.host_syncs"]
     assert syncs == 3 * 3                       # 3 windows x (2 hops + sink)
     verdicts = 0 if mode == "plain" else syncs
-    assert got["device.to_host"] == 2 * _N_CHUNKS + verdicts
-    # two 16-row uint32 columns per chunk; one bool per verdict row
+    assert got["device.to_host"] == 3 + verdicts        # one group a window
+    # every chunk's 16 records of 16 uint32 words; one bool per verdict row
     assert got["device.to_host_bytes"] == \
-        2 * _N_CHUNKS * 16 * 4 + (0 if mode == "plain" else 3 * _N_CHUNKS)
+        _N_CHUNKS * 16 * 16 * 4 + (0 if mode == "plain" else 3 * _N_CHUNKS)
     assert got["device.to_host_seconds"] > 0
     assert res["count"].sum() > 0
+
+
+def _flights_job(mode, reduce_fn, init, wc=4):
+    """The fixture's DelayedFlights job with the reducer given."""
+    from repro.dsl import stream
+    return (stream().map("identity", name=_HOPS[0], workers=2)
+            .filter("delay_filter_u32", const=15, name=_HOPS[1], workers=2)
+            .reduce(reduce_fn, init, name="sink").window(wc)
+            .seed(0).build(mode))
+
+
+def _flights_chunks(n=_N_CHUNKS):
+    from repro.data.synthetic import flight_chunks
+    return list(flight_chunks(16 * n, 16, seed=3))
+
+
+def _filtered(chunk):
+    """NumPy reference of the job's map and filter on one chunk."""
+    return np.where(chunk[:, 1:2].astype(np.int32) > 15, chunk, 0)
+
+
+def _both_reducers():
+    """carrier_delay_stats and sum folded side by side, recording every
+    argument the sink passes."""
+    from repro.dsl.reducers import resolve_reducer
+    cds, cds_init = resolve_reducer("carrier_delay_stats")
+    total, _ = resolve_reducer("sum")
+    calls = []
+
+    def fn(acc, chunk):
+        calls.append(chunk)
+        return cds(acc[0], chunk), total(acc[1], chunk)
+    return fn, (cds_init, None), calls
+
+
+@pytest.mark.parametrize("mode", ["plain", "encrypted", "enclave"])
+def test_reducer_gets_each_chunk_once_as_a_host_array(mode):
+    """On the window engine the reducer is called once per chunk, in
+    stream order, with a read-only NumPy row of the chunk's shape and
+    dtype, and folds to what the per-chunk oracle engine folds (over a
+    full window and a ragged one: the oracle seals chunk by chunk)."""
+    chunks = _flights_chunks(10)
+    out = {}
+    for wc in (4, 1):
+        fn, init, calls = _both_reducers()
+        res = _flights_job(mode, fn, init, wc).run(
+            jnp.asarray(c) for c in chunks)
+        out[wc] = res, calls
+    (win_cds, win_sum), calls = out[4]
+    (ref_cds, ref_sum), ref_calls = out[1]
+    assert len(calls) == len(ref_calls) == len(chunks)
+    for x, c in zip(calls, chunks):
+        assert isinstance(x, np.ndarray) and not x.flags.writeable
+        assert x.shape == c.shape and x.dtype == c.dtype
+        assert np.array_equal(x, _filtered(c))            # stream order
+    for x, y in zip(calls, ref_calls):
+        assert np.array_equal(x, np.asarray(y))
+    assert np.array_equal(win_cds["count"], ref_cds["count"])
+    assert np.array_equal(win_cds["sum"], ref_cds["sum"])
+    assert isinstance(win_sum, np.ndarray)
+    assert np.array_equal(win_sum, np.asarray(ref_sum))
+
+
+@pytest.mark.parametrize("tamper", [(9, 12, 13), (16, 17, 18, 19)],
+                         ids=["some-rows", "whole-window"])
+@pytest.mark.parametrize("mode", ["encrypted", "enclave"])
+def test_sink_never_brings_a_tampered_row_to_the_host(monkeypatch, mode,
+                                                      tamper):
+    """Tamper k rows of one egress window on the last hop's output edge:
+    the sink audits exactly k ``mac_failure`` events, brings only that
+    window's verified rows to the host, and the reducer sees only the
+    untampered chunks, in stream order."""
+    from repro.core import pipeline as P
+    from repro.core.pipeline import Pipeline
+    from repro.dsl.reducers import resolve_reducer
+
+    k = len(tamper)
+    pending = set(tamper)
+    orig_pool = Pipeline._worker_pool
+
+    def patched_pool(self, i, st):
+        pool = orig_pool(self, i, st)
+        if st.name != _HOPS[-1]:
+            return pool
+        for ex in pool:
+            orig_rsw = ex.run_static_window
+
+            def tampered(op, const, win, _orig=orig_rsw, **kw):
+                out, ok = _orig(op, const, win, **kw)
+                hit = [j for j, c in enumerate(out.counters)
+                       if c in pending]
+                if hit:
+                    pending.difference_update(out.counters[j] for j in hit)
+                    words = out.words
+                    for j in hit:             # flip one word, keep the tag
+                        words = words.at[j, 0].add(np.uint32(1))
+                    out = dataclasses.replace(out, words=words)
+                return out, ok
+
+            ex.run_static_window = tampered
+        return pool
+
+    monkeypatch.setattr(Pipeline, "_worker_pool", patched_pool)
+    shapes = []
+
+    def counted(x, _orig=P.to_host):
+        shapes.append(np.shape(x))
+        return _orig(x)
+
+    monkeypatch.setattr(P, "to_host", counted)
+
+    cds, init = resolve_reducer("carrier_delay_stats")
+    calls = []
+
+    def fn(acc, chunk):
+        calls.append(chunk)
+        return cds(acc, chunk)
+
+    p = _flights_job(mode, fn, init)
+    chunks = _flights_chunks()
+    nbytes = REGISTRY.counter("device.to_host_bytes")
+    b0 = nbytes.value
+    res = p.run(jnp.asarray(c) for c in chunks)
+
+    assert not pending                         # every target row was hit
+    kept = [_filtered(c) for i, c in enumerate(chunks) if i not in tamper]
+    assert len(calls) == len(kept) == _N_CHUNKS - k
+    for x, want in zip(calls, kept):
+        assert np.array_equal(x, want)
+    failures = p.directory.audit.events("mac_failure")
+    assert len(failures) == k
+    assert sorted(e.detail["row"] for e in failures) == sorted(tamper)
+    assert all(e.detail["stage"] == "sink" for e in failures)
+    assert p.metrics["sink"].mac_failures == k
+    assert p.metrics["sink"].chunks == _N_CHUNKS - k
+    # one group a window, the tampered window holding only its verified
+    # rows (none at all: no transfer); every other transfer is a verdict
+    groups = [s[0] for s in shapes if len(s) == 3]
+    want = [8, 8, 4]
+    want[min(tamper) // 8] -= k
+    assert groups == [n for n in want if n]
+    assert len(shapes) - len(groups) == 9
+    assert nbytes.value - b0 == (_N_CHUNKS - k) * 16 * 16 * 4 + 3 * _N_CHUNKS
+    recs = np.concatenate(kept)
+    delay = recs[:, 1].astype(np.int64)
+    valid = delay > 0
+    carrier = recs[valid, 0].astype(np.int64)
+    assert np.array_equal(res["count"],
+                          np.bincount(carrier, minlength=20))
+    assert np.array_equal(res["sum"], np.bincount(
+        carrier, weights=delay[valid], minlength=20))
 
 
 def test_to_host_equals_np_asarray_and_counts_device_arrays():
