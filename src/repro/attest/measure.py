@@ -85,17 +85,22 @@ def measure_fn(fn: Callable) -> bytes:
 
 
 def measure_stage(*, op: str = "custom", const: float = 0.0,
-                  fn: Optional[Callable] = None, sgx: bool = True) -> bytes:
+                  fn: Optional[Callable] = None, sgx: bool = True,
+                  table=None) -> bytes:
     """Measurement of one pipeline stage (repro.core.pipeline.Stage).
 
     Static-registry stages are measured by (op, const); custom stages by
     the code hash of their fn.  The sgx placement bit is part of the
     identity — moving a stage out of the enclave changes what you attest.
+    A table op's table enters by its digest (its full contents), so a
+    swapped table changes the identity.
     """
     parts = [b"stage", op.encode(), repr(float(const)).encode(),
              b"sgx" if sgx else b"plain"]
     if fn is not None:
         parts.append(measure_fn(fn))
+    if table is not None:
+        parts.append(b"table:" + _value_bytes(table))
     return measure_bytes(*parts)
 
 

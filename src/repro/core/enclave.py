@@ -49,6 +49,9 @@ from repro.obs.trace import NULL_TRACER
 # programs, where a counter would only fire at trace time
 _DISPATCHES = _METRICS.counter("device.dispatches")
 _DISP_CWMAC = _METRICS.counter("device.dispatches.cwmac.mac2")
+# record rows that enter an aggregate op's hop (every row of the share,
+# sealed or not: the host cannot tell an empty row from a record)
+_AGG_ROWS_IN = _METRICS.counter("aggregate.rows_in")
 
 U32 = jnp.uint32
 
@@ -136,6 +139,50 @@ class SealedWindow:
             counters=[self.counters[i] for i in idxs],
             epochs=[self.epochs[i] for i in idxs],
             meta=self.meta, n_words=self.n_words, window_id=self.window_id)
+
+
+@dataclass(frozen=True)
+class SealedTable:
+    """A table operand (``repro.kernels.enclave_map.TABLE_OPS``) sealed on
+    the device: ``words`` is the (64, 128) u32 table XORed with ChaCha20
+    (slot ``k`` of table row ``8r + k // 128`` takes word ``r`` of block
+    ``k + 1``), the layout in which the join kernel opens it in VMEM;
+    ``key_block`` is (1, 16) u32, the key words then the nonce words."""
+    words: jax.Array
+    key_block: jax.Array
+
+
+def _table_keystream(key_block: jax.Array) -> jax.Array:
+    n = 1024
+    ks = chacha20.chacha20_block_rows(
+        key_block[0, :8], jnp.broadcast_to(key_block[0, 8:11], (n, 3)),
+        jnp.arange(1, n + 1, dtype=U32))
+    return ks[:, :8].T.reshape(64, 128)
+
+
+def seal_table(key: StageKey, counter: int, table) -> SealedTable:
+    """Seal a (64, 128) u32 table onto the device under ``key`` at the
+    nonce of ``counter`` (a counter of the table's own session)."""
+    block = np.zeros((1, 16), np.uint32)
+    block[0, :8], block[0, 8:11] = key.key, key.nonce(counter)
+    block = jnp.asarray(block)
+    return SealedTable(words=jnp.asarray(table, U32)
+                       ^ _table_keystream(block), key_block=block)
+
+
+def open_table(table: SealedTable) -> jax.Array:
+    """The plaintext table, as XLA ops (``encrypted`` mode's trust)."""
+    return table.words ^ _table_keystream(table.key_block)
+
+
+def _hop_span(op: str) -> str:
+    """The span of a hop's host work: ``enclave.join`` for a table op,
+    ``enclave.count`` for an aggregate op, else ``enclave.op``."""
+    if op in enclave_ops.TABLE_OPS:
+        return "enclave.join"
+    if op in enclave_ops.AGGREGATE_OPS:
+        return "enclave.count"
+    return "enclave.op"
 
 
 def _blocks_batch(words: jax.Array) -> jax.Array:
@@ -299,13 +346,25 @@ def _uniform_runs(chunks: Sequence[SealedChunk]):
         yield group
 
 
-def _apply_static_words(op: str, const: float, words: jax.Array) -> jax.Array:
+def _apply_op(op: str, const: float, blocks: jax.Array,
+              table=None) -> jax.Array:
+    """A registered op on plaintext (rows, 16) blocks, as XLA ops; a table
+    op reads ``table``, plaintext or a :class:`SealedTable`."""
+    if op in enclave_ops.TABLE_OPS:
+        if isinstance(table, SealedTable):
+            table = open_table(table)
+        return enclave_ops.TABLE_OPS[op][0](blocks, table)
+    return enclave_ops.OPS[op](blocks, const)
+
+
+def _apply_static_words(op: str, const: float, words: jax.Array,
+                        table=None) -> jax.Array:
     """Batched mirror of :func:`_apply_static_f32` on raw payload words:
     (B, n_words) -> (B, n_words), the operator applied ONCE across every
     block row of the window."""
     B, n = words.shape
     blocks = _blocks_batch(words).reshape(-1, 16)
-    out = enclave_ops.OPS[op](blocks, const)
+    out = _apply_op(op, const, blocks, table)
     return out.reshape(B, -1)[:, :n]
 
 
@@ -470,7 +529,7 @@ class EnclaveExecutor:
                        epochs=out_epochs), ok
 
     def run_static_window(self, op: str, const: float, win: SealedWindow,
-                          *, reseal_as=None
+                          *, reseal_as=None, table=None
                           ) -> Tuple[SealedWindow, Optional[jax.Array]]:
         """Batched :meth:`run_static` on a whole window (deferred
         verdicts, see :meth:`run_window`): the steady-state hot path — a
@@ -485,10 +544,19 @@ class EnclaveExecutor:
         in enclave mode the fused kernel re-encrypts directly under the
         outbound coordinates, so plaintext stays VMEM-confined on the
         retry path too.
+
+        A table op (``enclave_map.TABLE_OPS``) reads ``table``: the
+        plaintext table in plain mode, else a :class:`SealedTable`, which
+        encrypted mode opens as XLA ops and enclave mode opens inside its
+        own kernel (``enclave_join_rows``).  An aggregate op
+        (``AGGREGATE_OPS``) combines every row of the share into partial
+        rows, the same in every mode.
         """
+        if op in enclave_ops.AGGREGATE_OPS:
+            _AGG_ROWS_IN.inc(len(win) * ((win.n_words + 15) // 16))
         if self.mode == "plain":
             return replace(win, words=_apply_static_words(
-                op, const, win.words)), None
+                op, const, win.words, table)), None
         out_view, out_ctrs, out_epochs = _reseal_coords(win, reseal_as)
         keys_in, nonces_in = _window_cipher_params(self.key_in, win)
         keys_out, nonces_out = _window_cipher_params(self.key_out, out_view)
@@ -498,10 +566,10 @@ class EnclaveExecutor:
                                   window=win.window_id):
                 pt, ok = aead.open_many(keys_in, nonces_in,
                                         win.words, win.tags)
-            with self.tracer.span("enclave.op", cat="dispatch",
+            with self.tracer.span(_hop_span(op), cat="dispatch",
                                   track=self.track, op=op, rows=len(win),
                                   window=win.window_id):
-                words = _apply_static_words(op, const, pt)
+                words = _apply_static_words(op, const, pt, table)
             with self.tracer.span("enclave.seal", cat="dispatch",
                                   track=self.track, rows=len(win),
                                   window=win.window_id):
@@ -519,7 +587,7 @@ class EnclaveExecutor:
                          axis=-1)
         # fused decrypt->op->encrypt over the window's flattened rows;
         # payload keystream offset is counter0=1 per chunk.
-        with self.tracer.span("enclave.op", cat="dispatch",
+        with self.tracer.span(_hop_span(op), cat="dispatch",
                               track=self.track, op=op, rows=B,
                               window=win.window_id):
             rows = _blocks_batch(win.words).reshape(-1, 16)
@@ -535,9 +603,15 @@ class EnclaveExecutor:
                 # (per-block keystream counters stay 1..n_blocks — the
                 # chunk counter only enters through the nonce)
                 kw["nonces_out"] = jnp.repeat(nonces_out, n_blocks, axis=0)
-            out_words = enclave_ops.enclave_map_rows(
-                row_kin, row_kout, row_nonces, row_ctrs, rows, op=op,
-                const=const, **kw).reshape(B, -1)[:, :n_words]
+            if op in enclave_ops.TABLE_OPS:
+                out = enclave_ops.enclave_join_rows(
+                    row_kin, row_kout, row_nonces, row_ctrs, rows,
+                    table.words, table.key_block, op=op, **kw)
+            else:
+                out = enclave_ops.enclave_map_rows(
+                    row_kin, row_kout, row_nonces, row_ctrs, rows, op=op,
+                    const=const, **kw)
+            out_words = out.reshape(B, -1)[:, :n_words]
         # re-tag under the outbound keys, batched
         with self.tracer.span("enclave.seal", cat="dispatch",
                               track=self.track, rows=B, window=win.window_id):
@@ -577,11 +651,12 @@ class EnclaveExecutor:
         return outs, oks[0] if len(oks) == 1 else jnp.concatenate(oks)
 
 
-def _apply_static_f32(op: str, const: float, x: jax.Array) -> jax.Array:
+def _apply_static_f32(op: str, const: float, x: jax.Array,
+                      table=None) -> jax.Array:
     """jnp mirror of the kernel's static op registry (on decoded tensors)."""
     words, meta = aead.tensor_to_words(x)
     blocks, n = _words_to_blocks(words)
-    out = enclave_ops.OPS[op](blocks, const)
+    out = _apply_op(op, const, blocks, table)
     return aead.words_to_tensor(out.reshape(-1)[:n], meta)
 
 

@@ -57,7 +57,9 @@ from repro.configs.base import SecureStreamConfig
 from repro.core import router as R
 from repro.core.enclave import (EnclaveExecutor, SealedChunk, SealedWindow,
                                 egress, egress_window, ingress, plain_window,
-                                seal_tensors_window, uniform_runs)
+                                seal_table, seal_tensors_window,
+                                uniform_runs)
+from repro.kernels.enclave_map.ops import AGGREGATE_OPS, TABLE_OPS
 from repro.obs.host import to_host
 from repro.obs.metrics import (REGISTRY as _METRICS, dispatch_count,
                                reset_dispatch_count)  # noqa: F401 (re-export)
@@ -84,6 +86,13 @@ class Stage:
     host in one transfer (the ``window_chunks=1`` oracle passes the
     device array).
 
+    A table op (``ysb_campaign_join``) reads a table that the op's rule
+    derives from ``const`` (:func:`stage_table`); the pipeline seals it
+    onto the device once, over an attested session of its own
+    (``table/<name>``), and its digest enters the stage's measurement.  An aggregate op (``ysb_window_count``) turns each
+    worker share into partial-count rows, which the merge and egress
+    carry like any rows.
+
     Stages are usually not built by hand anymore — ``repro.dsl.stream``
     / ``repro.dsl.load_spec`` compile to this dataclass (bit-identically;
     the hand-built form is kept as the tests' parity oracle).
@@ -96,6 +105,12 @@ class Stage:
     sgx: bool = True                     # paper: constraint:type==sgx
     reduce_fn: Optional[Callable] = None # terminal reduce (runs at egress)
     reduce_init: Any = None
+
+
+def stage_table(st: Stage) -> Optional[np.ndarray]:
+    """A table op's (64, 128) u32 table, by the op's rule from the stage's
+    ``const`` (``enclave_map.TABLE_OPS``); None for any other stage."""
+    return TABLE_OPS[st.op][1](st.const) if st.op in TABLE_OPS else None
 
 
 @dataclass
@@ -291,6 +306,10 @@ class Pipeline:
         ] if secure.mode != "plain" else [None] * (len(self.stages) + 1)
         self.metrics: Dict[str, StageMetrics] = {
             s.name: StageMetrics() for s in self.stages}
+        # each table op's table, on the device: sealed unless plain
+        self.tables: Dict[str, Any] = {s.name: self._provision_table(s)
+                                       for s in self.stages
+                                       if s.op in TABLE_OPS}
         self.monitor.attach(self)
 
     # -------------------------------------------------------- attestation
@@ -316,7 +335,8 @@ class Pipeline:
         d.enroll("io/source", IO_ENDPOINT, allow=True)
         d.enroll("io/sink", IO_ENDPOINT, allow=True)
         for st in self.stages:
-            m = measure_stage(op=st.op, const=st.const, fn=st.fn, sgx=st.sgx)
+            m = measure_stage(op=st.op, const=st.const, fn=st.fn, sgx=st.sgx,
+                              table=stage_table(st))
             d.policy.allow(m)
             d.enroll(f"stage/{st.name}", m)
             for w in range(max(1, st.workers)):
@@ -331,6 +351,23 @@ class Pipeline:
             if not d.has_session(f"edge{i}"):
                 d.establish(f"edge{i}", endpoints[i], endpoints[i + 1],
                             stage_id=i)
+
+    def _provision_table(self, st: Stage):
+        """The stage's table on the device.  Sealed modes seal it once
+        under the key of a session between the data's owner (the source
+        endpoint) and the stage, so only the stage's kernel opens it."""
+        if self.secure.mode == "plain":
+            return jnp.asarray(stage_table(st))
+        edge = f"table/{st.name}"
+        if not self.directory.has_session(edge):
+            self.directory.establish(edge, "io/source", f"stage/{st.name}")
+        h = self.directory.handle(edge)
+        return seal_table(h.key(), h.next_counter(), stage_table(st))
+
+    def _table_kw(self, st: Stage) -> Dict[str, Any]:
+        """``table=`` for a stage that reads one, else nothing."""
+        return {"table": self.tables[st.name]} if st.name in self.tables \
+            else {}
 
     def _live_workers(self, st: Stage) -> List[int]:
         """Worker indices still dispatchable.
@@ -439,7 +476,7 @@ class Pipeline:
                             out, ok = pool[w].run_window(st.fn, sub)
                         else:
                             out, ok = pool[w].run_static_window(
-                                st.op, st.const, sub)
+                                st.op, st.const, sub, **self._table_kw(st))
                         dispatches.append((pi, w, idxs, out, ok))
             verdicts = _sync_window(
                 [d[3].words for d in dispatches],
@@ -553,7 +590,8 @@ class Pipeline:
         coordinates (the re-execution path); None keeps steady state."""
         if st.fn is not None:
             return ex.run_window(st.fn, sub, reseal_as=coords)
-        return ex.run_static_window(st.op, st.const, sub, reseal_as=coords)
+        return ex.run_static_window(st.op, st.const, sub, reseal_as=coords,
+                                    **self._table_kw(st))
 
     def _ft_pick_survivor(self, st: Stage, ft, exclude: int,
                           prefer=None) -> Optional[int]:
@@ -1171,6 +1209,12 @@ class Pipeline:
                     "clamped it, build the pipeline with a "
                     "KeyDirectory(epoch_history=...) large enough for "
                     "the window/rekey combination")
+            whole = [s.name for s in self.stages
+                     if s.op in TABLE_OPS or s.op in AGGREGATE_OPS]
+            if whole:
+                raise ValueError(
+                    f"stages {whole} join a table or combine rows, which "
+                    f"only the window engine runs (window_chunks >= 2)")
             # the per-chunk oracle engine: scalar seal/open per chunk
             # with a blocking verdict sync per chunk (the seed engine,
             # kept as the degenerate case / bitwise oracle)

@@ -14,6 +14,12 @@ module is that surface for the window-vectorized engine::
            .reduce("carrier_delay_stats", name="reducer")
            .run(mode="enclave", rekey_every_n=1024))
 
+A stage that combines rows is ``.aggregate(op, ...)``, with the same
+keywords as ``.map``: the Yahoo Streaming Benchmark's campaign query is
+``.filter("ysb_view_filter")`` -> ``.map("ysb_campaign_join",
+const=<table seed>)`` -> ``.aggregate("ysb_window_count", const=10000)``
+-> ``.reduce("ysb_campaign_window_counts")``.
+
 Builders are immutable: every combinator returns a new
 :class:`StreamBuilder` (exactly like :class:`repro.core.observable
 .Observable`, whose :class:`~repro.core.observable.Op` nodes this module
@@ -35,6 +41,7 @@ from typing import Any, Callable, Iterable, Optional, Tuple, Union
 import jax.numpy as jnp
 
 from repro.core.observable import Observable, Op, describe_ops
+from repro.kernels.enclave_map.ops import TABLE_OPS
 
 
 class StreamBuilder:
@@ -93,6 +100,18 @@ class StreamBuilder:
         meta = self._stage_meta("filter", op, name, const, workers, sgx,
                                 len(self._ops))
         return self._with(Op("filter", fn, meta=meta))
+
+    def aggregate(self, op: str, *, name: Optional[str] = None,
+                  const: float = 0.0, workers: int = 1,
+                  sgx: bool = True) -> "StreamBuilder":
+        """Add an aggregate stage: a registered op that combines rows
+        (``repro.kernels.enclave_map.AGGREGATE_OPS``, such as YSB's
+        ``ysb_window_count``).  Each worker share of a window becomes
+        partial-aggregate rows, sealed like any rows; the terminal reduce
+        adds them up.  Same keywords as :meth:`map`."""
+        meta = self._stage_meta("aggregate", op, name, const, workers, sgx,
+                                len(self._ops))
+        return self._with(Op("aggregate", None, meta=meta))
 
     def reduce(self, fn: Union[str, Callable], init: Any = None, *,
                name: str = "reduce") -> "StreamBuilder":
@@ -292,7 +311,10 @@ class StreamBuilder:
         static stage becomes a pure-jnp map with the same record
         semantics as the secure engine (dense filters included), custom
         fns pass through, the terminal reduce folds in stream order.
-        Bit-identical to ``mode="plain"`` — the DSL's cleartext oracle.
+        Bit-identical to ``mode="plain"`` — the DSL's cleartext oracle —
+        except for an aggregate stage, which combines the rows of each
+        chunk here and of each worker share there: its partial rows
+        differ, and what the terminal reduce adds up from them agrees.
         """
         from repro.core.enclave import _apply_static_f32
         from repro.dsl.reducers import resolve_reducer
@@ -301,14 +323,16 @@ class StreamBuilder:
             raise ValueError("as_observable needs a source")
         obs = Observable.from_chunks(src)
         for o in self._ops:
-            if o.kind in ("map", "filter"):
+            if o.kind in ("map", "filter", "aggregate"):
                 if o.fn is not None:
                     obs = obs.map(o.fn)
                 else:
                     op, const = o.meta["op"], o.meta["const"]
+                    table = TABLE_OPS[op][1](const) if op in TABLE_OPS \
+                        else None
                     obs = obs.map(
-                        lambda c, _op=op, _k=const: _apply_static_f32(
-                            _op, _k, c))
+                        lambda c, _op=op, _k=const, _t=table:
+                        _apply_static_f32(_op, _k, c, _t))
             elif o.kind == "reduce":
                 fn, init = (o.fn, o.init) if o.fn is not None \
                     else resolve_reducer(o.meta["reducer"])

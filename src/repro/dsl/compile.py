@@ -38,7 +38,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 from repro.configs.base import SecureStreamConfig
 from repro.core.observable import Op
 from repro.core.pipeline import Pipeline, Stage
-from repro.kernels.enclave_map.ops import OPS
+from repro.kernels.enclave_map.ops import AGGREGATE_OPS, OPS, TABLE_OPS
 
 MODES = ("plain", "encrypted", "enclave")
 
@@ -95,10 +95,7 @@ def validate(ops: Sequence[Op], mode: str) -> List[dict]:
                 resolve_reducer(s["reducer"])       # raises with known names
             continue
         if s["fn"] is None:
-            if s["op"] not in OPS:
-                raise DSLValidationError(
-                    f"stage {name!r}: unknown static op {s['op']!r}; "
-                    f"registered ops: {sorted(OPS)}")
+            _check_static_op(s)
         elif mode == "enclave" and s["sgx"]:
             raise DSLValidationError(
                 f"stage {name!r}: a Python closure cannot run sgx=True "
@@ -107,6 +104,35 @@ def validate(ops: Sequence[Op], mode: str) -> List[dict]:
                 f"Use a registry op, or mark the stage sgx=False to run "
                 f"it on the encrypted (non-enclave) path.")
     return stages
+
+
+def _check_static_op(s: dict) -> None:
+    """A registered op, under the verb that fits it: an aggregate op
+    only under ``.aggregate``, and nothing else there; a table op's
+    ``const`` is its table seed, a whole number; an aggregate op's is
+    the window in ms, below 2^16 (the kernel divides 64-bit times by it
+    in 16-bit limbs)."""
+    name, op, kind = s["name"], s["op"], s["kind"]
+    if op not in OPS and op not in TABLE_OPS:
+        raise DSLValidationError(
+            f"stage {name!r}: unknown static op {op!r}; registered ops: "
+            f"{sorted(set(OPS) | set(TABLE_OPS))}")
+    if (kind == "aggregate") != (op in AGGREGATE_OPS):
+        raise DSLValidationError(
+            f"stage {name!r}: {op!r} under .{kind}(): ops that combine "
+            f"rows ({sorted(AGGREGATE_OPS)}) go under .aggregate(), and "
+            f"only they do")
+    const = float(s["const"])
+    if op in TABLE_OPS and not (const.is_integer()
+                                and 0 <= const < 2 ** 32):
+        raise DSLValidationError(
+            f"stage {name!r}: {op!r} takes a table seed, a whole number "
+            f"in [0, 2^32), got {s['const']!r}")
+    if op in AGGREGATE_OPS and not (const.is_integer()
+                                    and 0 < const < 2 ** 16):
+        raise DSLValidationError(
+            f"stage {name!r}: {op!r} takes a window in ms, a whole "
+            f"number in [1, 2^16), got {s['const']!r}")
 
 
 # ------------------------------------------------------------------- fusion

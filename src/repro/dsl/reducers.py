@@ -20,6 +20,9 @@ Built-ins:
 * ``carrier_delay_stats`` — the paper's own DelayedFlights benchmark
   (§5.2): per-carrier delayed-flight counts + delay sums over packed
   uint32 records (word 0 = carrier, word 1 = delay minutes).
+* ``ysb_campaign_window_counts`` — the Yahoo Streaming Benchmark's
+  result: views per (window slot, campaign), summed from the partial-count
+  rows of the ``ysb_window_count`` aggregate.
 * ``sum`` — elementwise running sum of chunks (the 8-stage acceptance
   pipeline's terminal fold).
 * ``count`` — number of chunks that reached the sink.
@@ -39,8 +42,10 @@ from typing import Any, Callable, Dict, Tuple
 
 import numpy as np
 
+from repro.data import ysb
 from repro.data.synthetic import CARRIER_WORD, DELAY_WORD
 from repro.obs.host import to_host
+from repro.obs.metrics import REGISTRY as _METRICS
 
 ReducerFactory = Callable[..., Tuple[Callable, Any]]
 
@@ -81,6 +86,39 @@ def _carrier_delay_stats(num_carriers: int = 20):
         return acc
     return fn, {"count": np.zeros(num_carriers),
                 "sum": np.zeros(num_carriers)}
+
+
+@register_reducer("ysb_campaign_window_counts")
+def _ysb_campaign_window_counts(windows: int = 5,
+                                campaigns: int = ysb.CAMPAIGNS):
+    """YSB's views per campaign per event-time window, exact, as
+    ``{"count": (windows, campaigns) int64}``.  A partial-count row
+    (``ysb_window_count``) holds the window number in words 0-1, the
+    campaign in word 2 and the count in word 3; a row of count 0 is
+    empty.  Window ``w`` lands in slot ``w % windows``: ``windows`` has to
+    cover the stream's span of event time.  Views joined to no campaign
+    add to the ``join.misses`` counter and to no campaign; every non-empty
+    row adds one to ``aggregate.keys_out``."""
+    keys_out = _METRICS.counter("aggregate.keys_out")
+    misses = _METRICS.counter("join.misses")
+
+    def fn(acc, chunk):
+        rows = to_host(chunk)
+        live = rows[:, 3] > 0
+        if not live.any():
+            return acc
+        rows = rows[live]
+        count = rows[:, 3].astype(np.int64)
+        window = rows[:, 0].astype(np.uint64) \
+            | (rows[:, 1].astype(np.uint64) << np.uint64(32))
+        slot = (window % np.uint64(windows)).astype(np.int64)
+        camp = rows[:, 2].astype(np.int64)
+        hit = camp < campaigns
+        keys_out.inc(len(rows))
+        misses.inc(int(count[~hit].sum()))
+        np.add.at(acc["count"], (slot[hit], camp[hit]), count[hit])
+        return acc
+    return fn, {"count": np.zeros((windows, campaigns), np.int64)}
 
 
 @register_reducer("sum")

@@ -5,13 +5,15 @@ import jax.numpy as jnp
 
 from repro.kernels import interpret_mode
 from repro.kernels.enclave_map.enclave_map import (  # noqa: F401
-    OPS, enclave_apply, enclave_apply_rows)
+    AGGREGATE_BLOCK_ROWS, AGGREGATE_OPS, OPS, TABLE_OPS, campaign_join_rows,
+    enclave_apply, enclave_apply_rows)
 from repro.obs.metrics import REGISTRY as _METRICS
 
 # each wrapper call launches exactly one jitted enclave program — count
 # it here, in the eager wrapper, never inside the traced kernel
 _DISPATCHES = _METRICS.counter("device.dispatches")
 _DISP_MAP = _METRICS.counter("device.dispatches.enclave_map")
+_DISP_JOIN = _METRICS.counter("device.dispatches.campaign_join")
 
 
 def enclave_map(key_in, key_out, nonce, counter0, data_blocks, *, op,
@@ -39,6 +41,43 @@ def enclave_map_rows(keys_in, keys_out, nonces, counters, rows, *, op,
     _DISPATCHES.inc()
     _DISP_MAP.inc()
     R = rows.shape[0]
+    kw = {}
+    if op in AGGREGATE_OPS:
+        # the op reads every row of the call: padded rows must not count
+        block_rows, kw["valid_rows"] = AGGREGATE_BLOCK_ROWS, R
+    args = _padded_rows(keys_in, keys_out, nonces, counters, rows,
+                        nonces_out, counters_out, block_rows)
+    out = enclave_apply_rows(*args[:5], op=op, const=const,
+                             block_rows=block_rows,
+                             interpret=interpret_mode(),
+                             nonces_out=args[5], counters_out=args[6], **kw)
+    return out[:R]
+
+
+def enclave_join_rows(keys_in, keys_out, nonces, counters, rows, table,
+                      table_key, *, op, block_rows: int = 256,
+                      nonces_out=None, counters_out=None):
+    """:func:`enclave_map_rows` for a table op (:data:`TABLE_OPS`): the
+    rows joined against the sealed ``table`` ((64, 128) u32) opened in
+    the kernel under ``table_key`` ((1, 16): key words, then nonce
+    words)."""
+    _DISPATCHES.inc()
+    _DISP_JOIN.inc()
+    R = rows.shape[0]
+    args = _padded_rows(keys_in, keys_out, nonces, counters, rows,
+                        nonces_out, counters_out, block_rows)
+    out = campaign_join_rows(*args[:5], table, table_key, op=op,
+                             block_rows=block_rows,
+                             interpret=interpret_mode(),
+                             nonces_out=args[5], counters_out=args[6])
+    return out[:R]
+
+
+def _padded_rows(keys_in, keys_out, nonces, counters, rows, nonces_out,
+                 counters_out, block_rows):
+    """Per-row cipher operands, every one padded to a tile multiple
+    (padded tail rows use zero cipher parameters)."""
+    R = rows.shape[0]
     ones = jnp.ones((R, 1), jnp.uint32)
     kin = keys_in.reshape(1, 8) * ones if keys_in.ndim == 1 else keys_in
     kout = keys_out.reshape(1, 8) * ones if keys_out.ndim == 1 else keys_out
@@ -47,17 +86,8 @@ def enclave_map_rows(keys_in, keys_out, nonces, counters, rows, *, op,
     if counters_out is None:
         counters_out = counters
     pad = (-R) % block_rows
-    if pad:
-        kin = jnp.pad(kin, ((0, pad), (0, 0)))
-        kout = jnp.pad(kout, ((0, pad), (0, 0)))
-        nonces = jnp.pad(nonces, ((0, pad), (0, 0)))
-        counters = jnp.pad(counters, (0, pad))
-        nonces_out = jnp.pad(nonces_out, ((0, pad), (0, 0)))
-        counters_out = jnp.pad(counters_out, (0, pad))
-        rows = jnp.pad(rows, ((0, pad), (0, 0)))
-    out = enclave_apply_rows(kin, kout, nonces, counters, rows, op=op,
-                             const=const, block_rows=block_rows,
-                             interpret=interpret_mode(),
-                             nonces_out=nonces_out,
-                             counters_out=counters_out)
-    return out[:R]
+    cols = (kin, kout, nonces, counters, rows, nonces_out, counters_out)
+    if not pad:
+        return cols
+    return tuple(jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+                 for a in cols)

@@ -1,7 +1,8 @@
 """Compile rehearsal: the window engine's Pallas kernels, compiled for a
 described TPU v5e (no chip attached) at the shapes one DelayedFlights
 window dispatches — chunk 1024 records, 2 workers per stage, window
-factor 8 (16 chunks, 16 384 records per window).
+factor 8 (16 chunks, 16 384 records per window) — and the YSB job's join
+and count at a worker's share and at a whole window.
 
 Nothing runs: a compile that passes says the TPU compiler (Mosaic)
 accepts the block specs and the kernel fits VMEM, not that it is fast or
@@ -18,7 +19,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P, \
 
 from repro.kernels.chacha20.chacha20 import chacha20_xor_rows
 from repro.kernels.cwmac.cwmac import mac_partials_batch
-from repro.kernels.enclave_map.enclave_map import enclave_apply_rows
+from repro.kernels.enclave_map.enclave_map import (AGGREGATE_BLOCK_ROWS,
+                                                   campaign_join_rows,
+                                                   enclave_apply_rows)
 
 # read off the engine (encrypted + enclave modes, one window):
 # seal/open rows = B chunks x (1024 blocks + the MAC-key block), padded to
@@ -30,6 +33,8 @@ MAC_LIMBS, MAC_TILE = 32768, 4096
 # enclave hop: one worker's share, 8 chunks x 1024 records, a row each
 ENCLAVE_ROWS = 8192
 BLOCK_ROWS = 256
+# the YSB join and count: a worker's share, and a whole engine window
+YSB_ROWS = (8192, 16384)
 
 
 @pytest.fixture(scope="module")
@@ -70,13 +75,36 @@ def test_mac_partials_batch_compiles_for_v5e(u32, batch):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("op", ["identity", "delay_filter_u32"])
+@pytest.mark.parametrize("op", ["identity", "delay_filter_u32",
+                                "ysb_view_filter"])
 def test_enclave_apply_rows_compiles_for_v5e(u32, op):
     fn = functools.partial(enclave_apply_rows, op=op, const=15.0,
                            block_rows=BLOCK_ROWS, interpret=False)
     R = ENCLAVE_ROWS
     text = _compiled_text(fn, u32(R, 8), u32(R, 8), u32(R, 3), u32(R),
                           u32(R, 16))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows", YSB_ROWS)
+def test_campaign_join_rows_compiles_for_v5e(u32, rows):
+    fn = functools.partial(campaign_join_rows, block_rows=BLOCK_ROWS,
+                           interpret=False)
+    text = _compiled_text(fn, u32(rows, 8), u32(rows, 8), u32(rows, 3),
+                          u32(rows), u32(rows, 16), u32(64, 128),
+                          u32(1, 16))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows", YSB_ROWS)
+def test_ysb_window_count_compiles_for_v5e(u32, rows):
+    """The count reads every row of the call: its output is one block,
+    and its opened rows stay in VMEM until the last grid step."""
+    fn = functools.partial(enclave_apply_rows, op="ysb_window_count",
+                           const=10000.0, block_rows=AGGREGATE_BLOCK_ROWS,
+                           interpret=False)
+    text = _compiled_text(fn, u32(rows, 8), u32(rows, 8), u32(rows, 3),
+                          u32(rows), u32(rows, 16))
     assert "tpu_custom_call" in text
 
 
