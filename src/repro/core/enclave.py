@@ -26,9 +26,19 @@ per-row device verdict vector without a host sync — the pipeline syncs
 once per window and drops failed rows there.  Mixed-epoch windows (a
 window straddling a ``rekey_every_n`` flip) resolve per-row keys, so
 rows never cross keystreams.
+
+Each worker share of a stage hop is ONE launch of a cached compiled
+program (:meth:`EnclaveExecutor.run_static_window`): the program is
+chosen by what the call shows (mode, op, shapes, key rank, a re-seal,
+a table), never built around a key, nonce or table, which are all its
+arguments.  The share gather and the merge are cached programs too, with
+their row indexes cached on the device, so a steady stream launches no
+eager device op per share.
 """
 from __future__ import annotations
 
+import functools
+from collections import OrderedDict
 from dataclasses import dataclass, replace
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -52,6 +62,13 @@ _DISP_CWMAC = _METRICS.counter("device.dispatches.cwmac.mac2")
 # record rows that enter an aggregate op's hop (every row of the share,
 # sealed or not: the host cannot tell an empty row from a record)
 _AGG_ROWS_IN = _METRICS.counter("aggregate.rows_in")
+# the window engine's cached programs: one hop program per signature
+# (compiles = cache misses, hits = launches of a resident program), and
+# the row gathers of worker fan-out and merge
+_HOP_COMPILES = _METRICS.counter("enclave.hop_programs.compiles")
+_HOP_HITS = _METRICS.counter("enclave.hop_programs.hits")
+_DISP_HOP = _METRICS.counter("device.dispatches.enclave.hop")
+_DISP_GATHER = _METRICS.counter("device.dispatches.stage.gather")
 
 U32 = jnp.uint32
 
@@ -131,14 +148,54 @@ class SealedWindow:
         return len(self.counters)
 
     def select(self, idxs: Sequence[int]) -> "SealedWindow":
-        """Row-gather a sub-window (ONE device gather per array)."""
-        idx = jnp.asarray(np.asarray(idxs, np.int32))
+        """Row-gather a sub-window: ONE launch of a cached program that
+        gathers words and tags together (:func:`take_rows`)."""
+        words, tags = take_rows([self.words],
+                                None if self.tags is None else [self.tags],
+                                idxs)
         return SealedWindow(
-            words=self.words[idx],
-            tags=None if self.tags is None else self.tags[idx],
+            words=words, tags=tags,
             counters=[self.counters[i] for i in idxs],
             epochs=[self.epochs[i] for i in idxs],
             meta=self.meta, n_words=self.n_words, window_id=self.window_id)
+
+
+_INDEX_CACHE: "OrderedDict[Tuple[int, ...], jax.Array]" = OrderedDict()
+_INDEX_CACHE_MAX = 256
+
+
+def device_index(idxs: Sequence[int]) -> jax.Array:
+    """An int32 row-index vector on the device, cached by its contents:
+    a steady stream repeats a few patterns (each worker's share of a
+    window, and the order that merges them), so it uploads nothing."""
+    key = tuple(idxs)
+    idx = _INDEX_CACHE.get(key)
+    if idx is None:
+        idx = jnp.asarray(np.asarray(key, np.int32))
+        _INDEX_CACHE[key] = idx
+        while len(_INDEX_CACHE) > _INDEX_CACHE_MAX:
+            _INDEX_CACHE.popitem(last=False)
+    else:
+        _INDEX_CACHE.move_to_end(key)
+    return idx
+
+
+@jax.jit
+def _take_rows(words, tags, idx):
+    cat = lambda xs: xs[0] if len(xs) == 1 else jnp.concatenate(xs)
+    return cat(words)[idx], None if tags is None else cat(tags)[idx]
+
+
+def take_rows(words: Sequence[jax.Array],
+              tags: Optional[Sequence[jax.Array]], idxs: Sequence[int]
+              ) -> Tuple[jax.Array, Optional[jax.Array]]:
+    """Rows ``idxs`` of the row-wise concatenation of ``words`` (and of
+    ``tags``, None in plain mode): one launch of a program that jit
+    caches by the parts' shapes."""
+    _DISPATCHES.inc()
+    _DISP_GATHER.inc()
+    return _take_rows(tuple(words), None if tags is None else tuple(tags),
+                      device_index(idxs))
 
 
 @dataclass(frozen=True)
@@ -216,23 +273,50 @@ def window_to_chunks(win: SealedWindow) -> List[SealedChunk]:
             for b in range(len(win))]
 
 
+_DEVICE_KEYS: "OrderedDict[bytes, jax.Array]" = OrderedDict()
+_DEVICE_KEYS_MAX = 64
+
+
+def _device_key(k: StageKey) -> jax.Array:
+    """The (8,) key on the device, uploaded once per key: a steady window
+    moves no key.  Cached by the key's own words, so a session
+    re-established at the same epoch can never alias an old copy."""
+    tag = np.asarray(k.key, np.uint32).tobytes()
+    dev = _DEVICE_KEYS.get(tag)
+    if dev is None:
+        dev = jnp.asarray(k.key)
+        _DEVICE_KEYS[tag] = dev
+        while len(_DEVICE_KEYS) > _DEVICE_KEYS_MAX:
+            _DEVICE_KEYS.popitem(last=False)
+    else:
+        _DEVICE_KEYS.move_to_end(tag)
+    return dev
+
+
+def _window_keys(key, epochs: Sequence[int]) -> jax.Array:
+    """Keys under ``key`` at each row's epoch.  Single-epoch windows (the
+    steady state) share one (8,) key — the cheaper shared-key compiled
+    program; mixed-epoch windows (rekey flips mid-window) get per-row
+    (B, 8) keys so no row is ever sealed/opened under another epoch's
+    keystream."""
+    if len(set(epochs)) == 1:
+        return _device_key(_key_at(key, epochs[0]))
+    ks = {e: np.asarray(_key_at(key, e).key) for e in set(epochs)}
+    return jnp.asarray(np.stack([ks[e] for e in epochs]))
+
+
+def _window_nonces(key, win: SealedWindow) -> jax.Array:
+    """(B, 3) nonces of the window's counters, built on the host in one
+    pass (``NonceExhaustedError`` before any launch) and uploaded once.
+    A nonce depends on its counter alone, not on the key or epoch."""
+    return jnp.asarray(_key_at(key, win.epochs[0]).nonces(win.counters))
+
+
 def _window_cipher_params(key, win: SealedWindow
                           ) -> Tuple[jax.Array, jax.Array]:
     """(keys, nonces) for a window under ``key`` at each row's ingress
-    epoch.  Single-epoch windows (the steady state) share one (8,) key —
-    the cheaper shared-key compiled program; mixed-epoch windows (rekey
-    flips mid-window) get per-row (B, 8) keys so no row is ever
-    sealed/opened under another epoch's keystream."""
-    if len(set(win.epochs)) == 1:
-        k = _key_at(key, win.epochs[0])
-        keys = jnp.asarray(k.key)
-        nonces = np.stack([np.asarray(k.nonce(c)) for c in win.counters])
-    else:
-        ks = [_key_at(key, e) for e in win.epochs]
-        keys = jnp.asarray(np.stack([np.asarray(k.key) for k in ks]))
-        nonces = np.stack([np.asarray(k.nonce(c))
-                           for k, c in zip(ks, win.counters)])
-    return keys, jnp.asarray(nonces)
+    epoch (:func:`_window_keys`, :func:`_window_nonces`)."""
+    return _window_keys(key, win.epochs), _window_nonces(key, win)
 
 
 def _reseal_coords(win: SealedWindow, reseal_as
@@ -366,6 +450,92 @@ def _apply_static_words(op: str, const: float, words: jax.Array,
     blocks = _blocks_batch(words).reshape(-1, 16)
     out = _apply_op(op, const, blocks, table)
     return out.reshape(B, -1)[:, :n]
+
+
+# -- the hop program: one cached compiled program per signature ------------
+
+_HOP_CACHE: "OrderedDict[Tuple, Any]" = OrderedDict()
+_HOP_CACHE_MAX = 64
+
+
+def _plain_hop(op, const, impl, words, table):
+    return impl(op, const, words, table)
+
+
+def _encrypted_hop(op, const, impl, words, tags, keys_in, keys_out,
+                   nonces_in, nonces_out, table):
+    pt, ok = aead.open_words(keys_in, nonces_in, words, tags)
+    y = impl(op, const, pt, None if table is None else SealedTable(*table))
+    ct, tags_out = aead.seal_words(
+        keys_out, nonces_in if nonces_out is None else nonces_out, y)
+    return ct, tags_out, ok
+
+
+def _enclave_hop(op, const, impl, words, tags, keys_in, keys_out,
+                 nonces_in, nonces_out, table):
+    """Check the ciphertext MACs (public data, outside the enclave), run
+    the fused decrypt -> op -> encrypt kernel over the share's block rows
+    (payload keystream counters 1..n_blocks per chunk), and re-tag under
+    the outbound keys."""
+    B, n_words = words.shape
+    n_blocks = (n_words + 15) // 16
+    ok = jnp.all(aead.mac2_words(
+        words, aead.mac_keys_rows(keys_in, nonces_in)) == tags, axis=-1)
+    rows = _blocks_batch(words).reshape(-1, 16)
+    row_nonces = jnp.repeat(nonces_in, n_blocks, axis=0)
+    row_ctrs = jnp.tile(jnp.arange(1, n_blocks + 1, dtype=U32), B)
+    row_kin = keys_in if keys_in.ndim == 1 \
+        else jnp.repeat(keys_in, n_blocks, axis=0)
+    row_kout = keys_out if keys_out.ndim == 1 \
+        else jnp.repeat(keys_out, n_blocks, axis=0)
+    kw = {}
+    if nonces_out is not None:
+        # the fused kernel re-encrypts under the FRESH coordinates (per-
+        # block keystream counters stay 1..n_blocks — the chunk counter
+        # only enters through the nonce)
+        kw["nonces_out"] = jnp.repeat(nonces_out, n_blocks, axis=0)
+    if table is None:
+        out = impl(row_kin, row_kout, row_nonces, row_ctrs, rows, op=op,
+                   const=const, **kw)
+    else:
+        out = impl(row_kin, row_kout, row_nonces, row_ctrs, rows, *table,
+                   op=op, **kw)
+    out_words = out.reshape(B, -1)[:, :n_words]
+    mk_out = aead.mac_keys_rows(
+        keys_out, nonces_in if nonces_out is None else nonces_out)
+    return out_words, aead.mac2_words(out_words, mk_out), ok
+
+
+def _hop_program(mode: str, op: str, const: float, shape: Tuple[int, int],
+                 key_in_rank: int = 0, key_out_rank: int = 0,
+                 reseal: bool = False, has_table: bool = False):
+    """The compiled program of one worker share's hop, cached by its
+    signature: (mode, op, const, (B, n_words), the key ranks — shared
+    (8,) or per-row (B, 8) —, a re-seal at fresh counters or not, a table
+    or not) and the op implementation it traces, so a rebound kernel
+    wrapper never reuses a program built around another.  Keys, nonces
+    and the table are the program's arguments, never its constants: a
+    rekey launches the same program."""
+    if mode == "enclave":
+        impl = enclave_ops.enclave_join_rows if has_table \
+            else enclave_ops.enclave_map_rows
+    else:
+        impl = _apply_static_words
+    ck = (mode, op, const, tuple(shape), key_in_rank, key_out_rank,
+          reseal, has_table, impl)
+    fn = _HOP_CACHE.get(ck)
+    if fn is None:
+        _HOP_COMPILES.inc()
+        body = {"plain": _plain_hop, "encrypted": _encrypted_hop,
+                "enclave": _enclave_hop}[mode]
+        fn = jax.jit(functools.partial(body, op, const, impl))
+        _HOP_CACHE[ck] = fn
+        while len(_HOP_CACHE) > _HOP_CACHE_MAX:
+            _HOP_CACHE.popitem(last=False)
+    else:
+        _HOP_HITS.inc()
+        _HOP_CACHE.move_to_end(ck)
+    return fn
 
 
 def plain_chunk(counter: int, x: jax.Array) -> SealedChunk:
@@ -532,18 +702,21 @@ class EnclaveExecutor:
                           *, reseal_as=None, table=None
                           ) -> Tuple[SealedWindow, Optional[jax.Array]]:
         """Batched :meth:`run_static` on a whole window (deferred
-        verdicts, see :meth:`run_window`): the steady-state hot path — a
-        handful of device dispatches per window regardless of B.
+        verdicts, see :meth:`run_window`): the steady-state hot path —
+        ONE launch of a cached hop program (:func:`_hop_program`) per
+        call, whatever B.
 
-        encrypted: ``open_many`` -> the op applied once across all block
-        rows -> ``seal_many``.  enclave: batched ciphertext MAC check +
-        one ``enclave_map_rows`` grid sweep (per-row nonce/counter, and
+        plain: the op applied once across all block rows.  encrypted:
+        open -> op -> seal.  enclave: the ciphertext MAC check, one
+        ``enclave_map_rows`` grid sweep (per-row nonce/counter, and
         per-row keys when the window straddles a rekey epoch flip), so
-        plaintext stays VMEM-confined row by row.  ``reseal_as`` seals
-        the output under a fresh counter block (see :meth:`run_window`);
-        in enclave mode the fused kernel re-encrypts directly under the
-        outbound coordinates, so plaintext stays VMEM-confined on the
-        retry path too.
+        plaintext stays VMEM-confined row by row, and the re-tag under
+        the outbound keys.  ``reseal_as`` seals the output under a fresh
+        counter block (see :meth:`run_window`); in enclave mode the fused
+        kernel re-encrypts directly under the outbound coordinates, so
+        plaintext stays VMEM-confined on the retry path too.  On the
+        host, ``enclave.open`` and ``enclave.seal`` resolve the inbound
+        and outbound keys and nonces, and the hop span wraps the launch.
 
         A table op (``enclave_map.TABLE_OPS``) reads ``table``: the
         plaintext table in plain mode, else a :class:`SealedTable`, which
@@ -555,70 +728,36 @@ class EnclaveExecutor:
         if op in enclave_ops.AGGREGATE_OPS:
             _AGG_ROWS_IN.inc(len(win) * ((win.n_words + 15) // 16))
         if self.mode == "plain":
-            return replace(win, words=_apply_static_words(
-                op, const, win.words, table)), None
+            prog = _hop_program("plain", op, const, win.words.shape,
+                                has_table=table is not None)
+            _DISPATCHES.inc()
+            _DISP_HOP.inc()
+            return replace(win, words=prog(win.words, table)), None
         out_view, out_ctrs, out_epochs = _reseal_coords(win, reseal_as)
-        keys_in, nonces_in = _window_cipher_params(self.key_in, win)
-        keys_out, nonces_out = _window_cipher_params(self.key_out, out_view)
-        if self.mode == "encrypted":
-            with self.tracer.span("enclave.open", cat="dispatch",
-                                  track=self.track, rows=len(win),
-                                  window=win.window_id):
-                pt, ok = aead.open_many(keys_in, nonces_in,
-                                        win.words, win.tags)
-            with self.tracer.span(_hop_span(op), cat="dispatch",
-                                  track=self.track, op=op, rows=len(win),
-                                  window=win.window_id):
-                words = _apply_static_words(op, const, pt, table)
-            with self.tracer.span("enclave.seal", cat="dispatch",
-                                  track=self.track, rows=len(win),
-                                  window=win.window_id):
-                ct, tags = aead.seal_many(keys_out, nonces_out, words)
-            return replace(win, words=ct, tags=tags, counters=out_ctrs,
-                           epochs=out_epochs), ok
-        # enclave: MAC check on ciphertext happens outside the enclave
-        # (public data), batched: one mac-key derivation + one MAC program.
-        B, n_words = len(win), win.n_words
-        n_blocks = (n_words + 15) // 16
+        B = len(win)
         with self.tracer.span("enclave.open", cat="dispatch",
                               track=self.track, rows=B, window=win.window_id):
-            mk_in = aead.derive_mac_keys_many(keys_in, nonces_in)
-            ok = jnp.all(aead.mac2_many(win.words, mk_in) == win.tags,
-                         axis=-1)
-        # fused decrypt->op->encrypt over the window's flattened rows;
-        # payload keystream offset is counter0=1 per chunk.
+            keys_in = _window_keys(self.key_in, win.epochs)
+            nonces_in = _window_nonces(self.key_in, win)
+        with self.tracer.span("enclave.seal", cat="dispatch",
+                              track=self.track, rows=B, window=win.window_id):
+            keys_out = _window_keys(self.key_out, out_epochs)
+            # steady state re-seals at the inbound counters: same nonces
+            nonces_out = None if reseal_as is None \
+                else _window_nonces(self.key_out, out_view)
+        prog = _hop_program(self.mode, op, const, win.words.shape,
+                            keys_in.ndim, keys_out.ndim,
+                            reseal_as is not None, table is not None)
+        tbl = None if table is None else (table.words, table.key_block)
         with self.tracer.span(_hop_span(op), cat="dispatch",
                               track=self.track, op=op, rows=B,
                               window=win.window_id):
-            rows = _blocks_batch(win.words).reshape(-1, 16)
-            row_nonces = jnp.repeat(nonces_in, n_blocks, axis=0)
-            row_ctrs = jnp.tile(jnp.arange(1, n_blocks + 1, dtype=U32), B)
-            row_kin = keys_in if keys_in.ndim == 1 \
-                else jnp.repeat(keys_in, n_blocks, axis=0)
-            row_kout = keys_out if keys_out.ndim == 1 \
-                else jnp.repeat(keys_out, n_blocks, axis=0)
-            kw = {}
-            if reseal_as is not None:
-                # the fused kernel re-encrypts under the FRESH coordinates
-                # (per-block keystream counters stay 1..n_blocks — the
-                # chunk counter only enters through the nonce)
-                kw["nonces_out"] = jnp.repeat(nonces_out, n_blocks, axis=0)
-            if op in enclave_ops.TABLE_OPS:
-                out = enclave_ops.enclave_join_rows(
-                    row_kin, row_kout, row_nonces, row_ctrs, rows,
-                    table.words, table.key_block, op=op, **kw)
-            else:
-                out = enclave_ops.enclave_map_rows(
-                    row_kin, row_kout, row_nonces, row_ctrs, rows, op=op,
-                    const=const, **kw)
-            out_words = out.reshape(B, -1)[:, :n_words]
-        # re-tag under the outbound keys, batched
-        with self.tracer.span("enclave.seal", cat="dispatch",
-                              track=self.track, rows=B, window=win.window_id):
-            mk_out = aead.derive_mac_keys_many(keys_out, nonces_out)
-            tags_out = aead.mac2_many(out_words, mk_out)
-        return replace(win, words=out_words, tags=tags_out,
-                       counters=out_ctrs, epochs=out_epochs), ok
+            _DISPATCHES.inc()
+            _DISP_HOP.inc()
+            words, tags, ok = prog(win.words, win.tags, keys_in, keys_out,
+                                   nonces_in, nonces_out, tbl)
+        return replace(win, words=words, tags=tags, counters=out_ctrs,
+                       epochs=out_epochs), ok
 
     # -- chunk-list wrappers over the window entry points -------------------
 

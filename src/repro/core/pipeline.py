@@ -58,7 +58,7 @@ from repro.core import router as R
 from repro.core.enclave import (EnclaveExecutor, SealedChunk, SealedWindow,
                                 egress, egress_window, ingress, plain_window,
                                 seal_table, seal_tensors_window,
-                                uniform_runs)
+                                take_rows, uniform_runs)
 from repro.kernels.enclave_map.ops import AGGREGATE_OPS, TABLE_OPS
 from repro.obs.host import to_host
 from repro.obs.metrics import (REGISTRY as _METRICS, dispatch_count,
@@ -488,8 +488,6 @@ class Pipeline:
             m.seconds += dt
             lat.observe(dt)
             m.windows += 1
-            disp = _DISPATCHES.value - d0
-            m.dispatches += disp
             off = 0
             marks: List[np.ndarray] = []
             for pi, w, idxs, out, _ in dispatches:
@@ -508,6 +506,12 @@ class Pipeline:
                                      worker=self.worker_id(st.name, w),
                                      row=out.counters[jj],
                                      epoch=out.epochs[jj])
+            with tr.span("stage.merge", cat="pipeline", track=st.name,
+                         windows=len(parts), window=wid):
+                merged = list(self._merge_outputs(parts, dispatches, marks))
+            # the round's launches: its shares, their gathers, the merge
+            disp = _DISPATCHES.value - d0
+            m.dispatches += disp
             mon = self.monitor
             if mon.enabled:
                 wrows: Dict[int, int] = {}
@@ -519,9 +523,6 @@ class Pipeline:
                     seconds=dt, queue_rows=got, worker_rows=wrows,
                     min_epoch=min(min(p.epochs) for p in parts),
                     dispatches=disp)
-            with tr.span("stage.merge", cat="pipeline", track=st.name,
-                         windows=len(parts), window=wid):
-                merged = list(self._merge_outputs(parts, dispatches, marks))
             yield from merged
 
     @staticmethod
@@ -529,7 +530,9 @@ class Pipeline:
         """Reassemble each input window's surviving rows in original
         stream order.  The all-survived single-dispatch case (steady
         state) passes the worker's output through untouched; otherwise
-        one concatenate + one gather rebuilds the window."""
+        the host orders the surviving rows and ONE launch of a cached
+        program concatenates the shares and gathers them
+        (:func:`repro.core.enclave.take_rows`)."""
         for pi in range(len(parts)):
             ds = [(d, mk) for d, mk in zip(dispatches, marks)
                   if d[0] == pi]
@@ -540,11 +543,6 @@ class Pipeline:
                 yield ds[0][0][3]
                 continue
             outs = [d[3] for d, _ in ds]
-            cat_w = outs[0].words if len(outs) == 1 \
-                else jnp.concatenate([o.words for o in outs])
-            cat_t = outs[0].tags
-            if cat_t is not None and len(outs) > 1:
-                cat_t = jnp.concatenate([o.tags for o in outs])
             entries = []             # (orig row, concat pos, counter, epoch)
             pos = 0
             for (_, _, idxs, out, _), mk in ds:
@@ -555,10 +553,12 @@ class Pipeline:
             if not entries:
                 continue
             entries.sort()
-            sel = jnp.asarray(np.asarray([e[1] for e in entries], np.int32))
+            words, tags = take_rows(
+                [o.words for o in outs],
+                None if outs[0].tags is None else [o.tags for o in outs],
+                [e[1] for e in entries])
             yield SealedWindow(
-                words=cat_w[sel],
-                tags=None if cat_t is None else cat_t[sel],
+                words=words, tags=tags,
                 counters=[e[2] for e in entries],
                 epochs=[e[3] for e in entries],
                 meta=outs[0].meta, n_words=outs[0].n_words,
@@ -892,8 +892,6 @@ class Pipeline:
             m.seconds += dt
             lat.observe(dt)
             m.windows += 1
-            disp = _DISPATCHES.value - d0
-            m.dispatches += disp
             # ---- per-row accounting + replay scheduling
             off = 0
             final = []               # dispatch tuples fed to the merge
@@ -962,6 +960,12 @@ class Pipeline:
                         # already audited on the original verdict
                     final.append((pi, wr, row_js, out2, None))
                     marks.append(v2)
+            with tr.span("stage.merge", cat="pipeline", track=st.name,
+                         windows=len(parts), window=wid):
+                merged = list(self._merge_outputs(parts, final, marks))
+            # the round's launches: shares, retries, replays, the merge
+            disp = _DISPATCHES.value - d0
+            m.dispatches += disp
             mon = self.monitor
             if mon.enabled:
                 wrows: Dict[int, int] = {}
@@ -974,9 +978,6 @@ class Pipeline:
                     seconds=dt, queue_rows=got, worker_rows=wrows,
                     min_epoch=min(min(p.epochs) for p in parts),
                     dispatches=disp)
-            with tr.span("stage.merge", cat="pipeline", track=st.name,
-                         windows=len(parts), window=wid):
-                merged = list(self._merge_outputs(parts, final, marks))
             # the round's verdicts are folded in: release retained rows
             ft.buffer.ack(st.name, rnd)
             yield from merged

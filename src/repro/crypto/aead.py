@@ -96,8 +96,6 @@ _FP_HITS = _METRICS.counter("aead.fastpath.hits")
 _DISPATCHES = _METRICS.counter("device.dispatches")
 _DISP_SEAL = _METRICS.counter("device.dispatches.aead.seal_many")
 _DISP_OPEN = _METRICS.counter("device.dispatches.aead.open_many")
-_DISP_MACKEYS = _METRICS.counter("device.dispatches.aead.mac_keys_many")
-_DISP_MAC2 = _METRICS.counter("device.dispatches.aead.mac2_many")
 
 
 def _resolve_backend(backend: Optional[str]) -> str:
@@ -170,15 +168,22 @@ def open_words(key, nonces, cts, tags, *, backend=_DEFAULT_BACKEND):
     return pt, jnp.all(expect == tags, axis=-1)
 
 
-def _mac_keys_rows(key, nonces):
+@jax.jit
+def mac_keys_rows(key, nonces):
     """(B, 4) clamped CW-MAC keys from keystream block 0 of each row —
-    the batched form of :func:`derive_mac_keys` (one rolled ChaCha pass)."""
+    the batched form of :func:`derive_mac_keys` (one rolled ChaCha pass).
+    ``key``: (8,) shared or (B, 8) per-item; ``nonces``: (B, 3).  The
+    enclave hop program MACs ciphertext with it outside the fused kernel
+    (ciphertext is public); jitted, so each hop program reuses one trace
+    of its ChaCha rounds."""
     zeros = jnp.zeros((nonces.shape[0],), U32)
     blk = chacha20.chacha20_block_rows(key, nonces, zeros)
     return _clamp(blk[:, :4])
 
 
-def _mac2_words(words, mac_keys, *, backend):
+def mac2_words(words, mac_keys, *, backend=_DEFAULT_BACKEND):
+    """Dual CW-MAC of (B, n_words) u32 under (B, 4) mac-key rows -> (B, 2)
+    tags; traceable, like :func:`mac_keys_rows`."""
     return _mac2_batch(words, mac_keys, backend)
 
 
@@ -189,12 +194,8 @@ def _cached_program(op: str, B: int, n_words: int, backend: str,
     fn = _COMPILE_CACHE.get(ck)
     if fn is None:
         _FP_COMPILES.inc()
-        impl = {"seal": seal_words, "open": open_words,
-                "mac2": _mac2_words}.get(op)
-        if impl is None:                       # mackeys takes no backend kw
-            fn = jax.jit(_mac_keys_rows)
-        else:
-            fn = jax.jit(functools.partial(impl, backend=backend))
+        impl = {"seal": seal_words, "open": open_words}[op]
+        fn = jax.jit(functools.partial(impl, backend=backend))
         _COMPILE_CACHE[ck] = fn
         while len(_COMPILE_CACHE) > _COMPILE_CACHE_MAX:
             _COMPILE_CACHE.popitem(last=False)
@@ -254,42 +255,6 @@ def open_many(key: jax.Array, nonces: jax.Array, cts: jax.Array,
     _DISPATCHES.inc()
     _DISP_OPEN.inc()
     return fn(key.astype(U32), nonces.astype(U32), cts, tags.astype(U32))
-
-
-def derive_mac_keys_many(key: jax.Array, nonces: jax.Array) -> jax.Array:
-    """Batched MAC-key derivation: (B, 4) clamped (r1, s1, r2, s2) rows.
-
-    ``key``: (8,) shared or (B, 8) per-item; ``nonces``: (B, 3).  Row b
-    equals ``derive_mac_keys(key_b, nonces[b])`` — used by the enclave
-    executor's window path, which MACs ciphertext *outside* the fused
-    kernel (ciphertext is public) but must not pay B scalar dispatches.
-    Programs share the seal/open compile cache (:func:`fastpath_stats`).
-    """
-    key, nonces = jnp.asarray(key), jnp.asarray(nonces)
-    if nonces.ndim != 2 or nonces.shape[1] != 3:
-        raise ValueError(f"derive_mac_keys_many expects nonces (B, 3), "
-                         f"got {nonces.shape}")
-    fn = _cached_program("mackeys", nonces.shape[0], 0, "jnp",
-                         key.ndim == 2)
-    _DISPATCHES.inc()
-    _DISP_MACKEYS.inc()
-    return fn(key.astype(U32), nonces.astype(U32))
-
-
-def mac2_many(words: jax.Array, mac_keys: jax.Array, *,
-              backend: Optional[str] = None) -> jax.Array:
-    """Batched dual CW-MAC: (B, n_words) u32 under (B, 4) mac-key rows ->
-    (B, 2) tags, one cached program per (B, n_words) shape."""
-    backend = _resolve_backend(backend)
-    words, mac_keys = jnp.asarray(words), jnp.asarray(mac_keys)
-    if words.ndim != 2 or mac_keys.shape != (words.shape[0], 4):
-        raise ValueError(f"mac2_many expects words (B, n) and mac_keys "
-                         f"(B, 4); got {words.shape} / {mac_keys.shape}")
-    fn = _cached_program("mac2", words.shape[0], words.shape[1], backend,
-                         True)
-    _DISPATCHES.inc()
-    _DISP_MAC2.inc()
-    return fn(words.astype(U32), mac_keys.astype(U32))
 
 
 def fastpath_stats() -> Dict[str, int]:

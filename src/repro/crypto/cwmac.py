@@ -115,11 +115,21 @@ def to_limbs_batch(words: jax.Array) -> jax.Array:
 
 
 def r_powers_batch(r: jax.Array, n: int) -> jax.Array:
-    """Per-row [r_b^n .. r_b^1]: (B,) keys -> (B, n) powers, log-doubling."""
-    asc = jnp.asarray(r, U32).reshape(-1, 1)
-    while asc.shape[1] < n:
-        asc = jnp.concatenate([asc, mulmod(asc, asc[:, -1:])], axis=1)
-    return asc[:, :n][:, ::-1]
+    """Per-row [r_b^n .. r_b^1]: (B,) keys -> (B, n) powers, by square and
+    multiply over the exponents' bits: one rolled loop of log2(n) steps,
+    so every program that MACs traces and lowers a few ops, not an
+    unrolled doubling chain."""
+    r = jnp.asarray(r, U32).reshape(-1, 1)
+    exps = jnp.arange(n, 0, -1, dtype=U32)[None, :]
+
+    def step(j, carry):
+        acc, sq = carry
+        bit = (exps >> j.astype(U32)) & np.uint32(1)
+        return jnp.where(bit == 1, mulmod(acc, sq), acc), mulmod(sq, sq)
+
+    acc, _ = jax.lax.fori_loop(0, max(1, int(n).bit_length()), step,
+                               (jnp.ones((r.shape[0], n), U32), r))
+    return acc
 
 
 def mac_batch(words: jax.Array, r: jax.Array, s: jax.Array) -> jax.Array:

@@ -61,6 +61,18 @@ class StageKey:
                          (chunk_counter >> 32) & 0xFFFFFFFF],
                         dtype=np.uint32)
 
+    def nonces(self, chunk_counters) -> np.ndarray:
+        """(B, 3) u32: row b is ``nonce(chunk_counters[b])``, built in one
+        vectorised pass, with the same exhaustion check on every row."""
+        if len(chunk_counters):
+            lo, hi = min(chunk_counters), max(chunk_counters)
+            self.nonce(lo if lo < 0 else hi)
+        c = np.asarray(chunk_counters, np.uint64)
+        out = np.zeros((c.shape[0], 3), np.uint32)
+        out[:, 1] = c & np.uint64(0xFFFFFFFF)
+        out[:, 2] = c >> np.uint64(32)
+        return out
+
 
 def resolve_key(key, epoch: int = None) -> "StageKey":
     """Resolve a StageKey or a KeyDirectory EdgeHandle at an epoch.

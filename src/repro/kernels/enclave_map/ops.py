@@ -9,11 +9,12 @@ from repro.kernels.enclave_map.enclave_map import (  # noqa: F401
     enclave_apply, enclave_apply_rows)
 from repro.obs.metrics import REGISTRY as _METRICS
 
-# each wrapper call launches exactly one jitted enclave program — count
-# it here, in the eager wrapper, never inside the traced kernel
+# an eager enclave_map call launches exactly one jitted enclave program —
+# count it here, in the eager wrapper, never inside the traced kernel.
+# The row wrappers below run traced inside the window engine's hop
+# program (repro.core.enclave), which counts that program's launch.
 _DISPATCHES = _METRICS.counter("device.dispatches")
 _DISP_MAP = _METRICS.counter("device.dispatches.enclave_map")
-_DISP_JOIN = _METRICS.counter("device.dispatches.campaign_join")
 
 
 def enclave_map(key_in, key_out, nonce, counter0, data_blocks, *, op,
@@ -37,9 +38,8 @@ def enclave_map_rows(keys_in, keys_out, nonces, counters, rows, *, op,
     ``nonces_out``/``counters_out`` re-encrypt under separate outbound
     coordinates (fault-tolerant re-execution: the inbound coordinates
     were already spent on the outbound key by the first dispatch).
+    Traceable: it counts no launch (see the module's counters).
     """
-    _DISPATCHES.inc()
-    _DISP_MAP.inc()
     R = rows.shape[0]
     kw = {}
     if op in AGGREGATE_OPS:
@@ -60,9 +60,7 @@ def enclave_join_rows(keys_in, keys_out, nonces, counters, rows, table,
     """:func:`enclave_map_rows` for a table op (:data:`TABLE_OPS`): the
     rows joined against the sealed ``table`` ((64, 128) u32) opened in
     the kernel under ``table_key`` ((1, 16): key words, then nonce
-    words)."""
-    _DISPATCHES.inc()
-    _DISP_JOIN.inc()
+    words).  Traceable, like :func:`enclave_map_rows`."""
     R = rows.shape[0]
     args = _padded_rows(keys_in, keys_out, nonces, counters, rows,
                         nonces_out, counters_out, block_rows)

@@ -138,9 +138,12 @@ def test_seeded_chaos_sweep_bit_identical(seed):
 def test_chaos_recovery_never_reuses_key_nonce(monkeypatch):
     """The FT invariant: a retried / failed-over / replayed share must
     never reseal under a (key, nonce) pair already spent on the outbound
-    key.  Spy on every AEAD seal (scalar and batched) across a fault
-    schedule that exercises retry, tamper-replay, AND verdict-drop
-    replay; any reuse trips the spy."""
+    key.  Spy on every AEAD seal (scalar and batched) and on the
+    outbound coordinates of every hop program launch (a stage hop seals
+    inside that one program) across a fault schedule that exercises
+    retry, tamper-replay, AND verdict-drop replay; any reuse trips the
+    spy."""
+    from repro.core import enclave
     from repro.crypto import aead
 
     # the oracle pipeline shares the chaos run's key seed — build it
@@ -155,19 +158,35 @@ def test_chaos_recovery_never_reuses_key_nonce(monkeypatch):
         assert kn not in seen, "(key, nonce) pair reused by a recovery"
         seen.add(kn)
 
+    def record_rows(key, nonces):
+        key, nonces = np.asarray(key), np.asarray(nonces)
+        for b in range(nonces.shape[0]):
+            record(key if key.ndim == 1 else key[b], nonces[b])
+
     def spy(key, nonce, words):
         record(key, nonce)
         return real_seal(key, nonce, words)
 
     def spy_many(key, nonces, words, **kw):
-        key = np.asarray(key)
-        for b in range(np.asarray(nonces).shape[0]):
-            record(key if key.ndim == 1 else key[b],
-                   np.asarray(nonces)[b])
+        record_rows(key, nonces)
         return real_seal_many(key, nonces, words, **kw)
+
+    real_hop = enclave._hop_program
+
+    def spy_hop(mode, *a, **kw):
+        prog = real_hop(mode, *a, **kw)
+
+        def launch(words, tags, keys_in, keys_out, nonces_in, nonces_out,
+                   table):
+            record_rows(keys_out,
+                        nonces_in if nonces_out is None else nonces_out)
+            return prog(words, tags, keys_in, keys_out, nonces_in,
+                        nonces_out, table)
+        return launch
 
     monkeypatch.setattr(aead, "seal", spy)
     monkeypatch.setattr(aead, "seal_many", spy_many)
+    monkeypatch.setattr(enclave, "_hop_program", spy_hop)
 
     plan = ChaosPlan(faults=[
         # crash AFTER the share ran: the original coordinates were

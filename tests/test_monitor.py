@@ -331,11 +331,11 @@ def _linear8(mode, wc=8):
 
 def test_dispatch_gate_8stage_encrypted_window_job():
     """THE per-hop dispatch-count regression gate (ROADMAP megakernel
-    item): the 8-stage encrypted window job costs exactly 2 launches per
-    stage window (open_many + seal_many), 1 per ingress window
-    (seal_many) and 1 per egress window (open_many).  A fused megakernel
-    must DROP these numbers; a regression to per-chunk dispatching would
-    multiply them by the window size."""
+    item): the 8-stage encrypted window job costs exactly 1 launch per
+    stage window (the hop program: open -> op -> seal), 1 per ingress
+    window (seal_many) and 1 per egress window (open_many).  A fused
+    megakernel must DROP these numbers; a regression to per-chunk
+    dispatching would multiply them by the window size."""
     reset_dispatch_count()
     p = _linear8("encrypted")
     src = _src(8)                       # exactly one 8-chunk window
@@ -345,18 +345,19 @@ def test_dispatch_gate_8stage_encrypted_window_job():
     rep = p.report()
     for i in range(8):
         assert rep[f"s{i}"]["windows"] == 1
-        assert rep[f"s{i}"]["dispatches"] == 2
-        assert rep[f"s{i}"]["dispatches_per_window"] == 2.0
+        assert rep[f"s{i}"]["dispatches"] == 1
+        assert rep[f"s{i}"]["dispatches_per_window"] == 1.0
     assert rep["dispatch"]["ingress"] == {"windows": 1, "dispatches": 1}
     assert rep["dispatch"]["egress"] == {"windows": 1, "dispatches": 1}
-    assert rep["dispatch"]["total"] == 8 * 2 + 1 + 1
+    assert rep["dispatch"]["total"] == 8 * 1 + 1 + 1
     assert dispatch_count() == rep["dispatch"]["total"]
 
 
 def test_dispatch_gate_8stage_enclave_window_job():
-    """Enclave hops pin at 5 launches per stage window: mac-key derive +
-    ciphertext MAC on the way in, the fused enclave_map_rows program,
-    and mac-key derive + re-MAC on the way out."""
+    """Enclave hops pin at 1 launch per stage window: one hop program
+    holds the mac-key derive + ciphertext MAC on the way in, the fused
+    enclave_map_rows kernel, and the mac-key derive + re-MAC on the way
+    out."""
     reset_dispatch_count()
     p = _linear8("enclave")
     src = _src(8)
@@ -365,10 +366,10 @@ def test_dispatch_gate_8stage_enclave_window_job():
     assert len(got) == 8
     rep = p.report()
     for i in range(8):
-        assert rep[f"s{i}"]["dispatches_per_window"] == 5.0
+        assert rep[f"s{i}"]["dispatches_per_window"] == 1.0
     assert rep["dispatch"]["ingress"]["dispatches"] == 1
     assert rep["dispatch"]["egress"]["dispatches"] == 1
-    assert dispatch_count() == 8 * 5 + 1 + 1
+    assert dispatch_count() == 8 * 1 + 1 + 1
 
 
 def test_monitored_8stage_rekey_revocation_bit_identical():
@@ -475,7 +476,7 @@ def test_dsl_monitor_verb_and_run_override():
     snap = sb.health_monitor.snapshot()
     assert snap["stages"]["m"]["windows_total"] == 2
     rep = sb.report()["m"]
-    assert rep["windows"] == 2 and rep["dispatches_per_window"] == 2.0
+    assert rep["windows"] == 2 and rep["dispatches_per_window"] == 1.0
     # unmonitored builders stay unmonitored (zero-cost default)
     assert stream(src).map("identity").health_monitor is None
     # per-run override on a bare pipeline

@@ -78,6 +78,19 @@ def test_r_powers_log_doubling_correct():
     assert list(ps) == want
 
 
+@pytest.mark.parametrize("n", [1, 2, 37, 128, 4096])
+def test_r_powers_batch_square_and_multiply_correct(n):
+    """The batched powers (every MAC program's power table, one rolled
+    loop over the exponents' bits) equal r^e mod p, row by row."""
+    from repro.crypto.cwmac import r_powers_batch
+    p = (1 << 31) - 1
+    rs = [1, 2, 123456789, p - 2]
+    ps = np.asarray(r_powers_batch(jnp.asarray(rs, jnp.uint32), n))
+    assert ps.shape == (len(rs), n)
+    for row, r in zip(ps, rs):
+        assert list(row) == [pow(r, e, p) for e in range(n, 0, -1)]
+
+
 def test_mlp_gelu_vs_swiglu_param_difference():
     import dataclasses
     from repro.configs import get_model_config

@@ -130,3 +130,42 @@ def test_sealed_keyed_route_seals_per_shard_on_v5e_2x2(topo, monkeypatch):
     assert "tpu_custom_call" in text
     assert len(re.findall(r"all-to-all", text)) == 1
     assert "all-gather" not in text
+
+
+@pytest.mark.parametrize("op,rows", [("delay_filter_u32", ENCLAVE_ROWS),
+                                     ("ysb_campaign_join", YSB_ROWS[0]),
+                                     ("ysb_window_count", YSB_ROWS[0])])
+def test_hop_program_keeps_each_kernel_name_for_v5e(u32, op, rows,
+                                                    monkeypatch):
+    """One worker share's hop program — the ciphertext MAC check, the
+    fused enclave kernel and the re-tag in one compiled program — keeps
+    each kernel as its own instruction, under its own name and with the
+    operand shapes of a standalone call: the names and shapes the chip
+    benchmark's device-trace readers match."""
+    from repro.core import enclave
+    from repro.kernels.cwmac import ops as cwmac_ops
+    from repro.kernels.enclave_map import ops as enclave_ops
+    for mod in (cwmac_ops, enclave_ops):  # this process's backend is CPU
+        monkeypatch.setattr(mod, "interpret_mode", lambda: False)
+    B, n = 8, rows * 16 // 8
+    table = op in enclave_ops.TABLE_OPS
+    impl = enclave_ops.enclave_join_rows if table \
+        else enclave_ops.enclave_map_rows
+    body = functools.partial(enclave._enclave_hop, op, 10000.0, impl)
+    text = _compiled_text(body, u32(B, n), u32(B, 2), u32(8), u32(8),
+                          u32(B, 3), None,
+                          (u32(64, 128), u32(1, 16)) if table else None)
+    kernel = "campaign_join_rows" if table else "enclave_apply_rows"
+    calls = re.findall(rf"^\s*%{kernel}(?:\.\d+)? = (u32\[[0-9,]+\]).*?"
+                       r"operand_layout_constraints=\{([^=]*)\}, ", text,
+                       re.M)
+    assert len(calls) == 1, kernel
+    out, operands = calls[0]
+    shapes = re.findall(r"u32\[([0-9,]+)\]", operands)
+    cols = [8, 8, 3, 1, 3, 1, 16] + ([128, 16] if table else [])
+    assert out == f"u32[{rows},16]"
+    assert shapes[:7] == [f"{rows},{c}" for c in cols[:7]]
+    assert shapes[7:] == (["64,128", "1,16"] if table else [])
+    # the inbound check and the outbound re-tag: one MAC kernel each
+    assert len(re.findall(r"^\s*%mac_partials_batch(?:\.\d+)? = ", text,
+                          re.M)) == 2
