@@ -12,16 +12,20 @@ seals whole windows with the batched AEAD fast path behind a small
 prefetch/double-buffer (window N+1's seal is dispatched before window N
 is handed downstream, so it overlaps downstream compute via JAX async
 dispatch), with nonce-counter blocks reserved per window from the
-directory.  Each stage dispatches every worker's per-window queue as ONE
-batched open -> operator -> seal program chain
-(:meth:`repro.core.enclave.EnclaveExecutor.run_static_many`), and MAC
-verdicts are **deferred**: per-row verdicts stay on device and sync to
-host once per hop per window (each stage, then the sink) — failed rows
-are dropped there and counted as ``mac_failures`` — instead of one
-blocking ``bool()`` per chunk.
+directory.  Each stage hop runs every worker's per-window share as ONE
+launch of a cached compiled open -> operator -> seal program
+(:meth:`repro.core.enclave.EnclaveExecutor.run_static_window` ->
+``repro.core.enclave._hop_program``), and MAC verdicts are
+**deferred**: per-row verdicts stay on device and sync to host once per
+hop per window (each stage, then the sink) — failed rows are dropped
+there and counted as ``mac_failures`` — instead of one blocking
+``bool()`` per chunk.  One loop, :meth:`Pipeline._stage_stream`, runs
+every round of every hop; fault tolerance (``retry``/``chaos``) is
+hooks inside that loop (retained inputs, per-share retry and failover,
+replays of lost rows), not a second loop.
 ``window_chunks=1`` degenerates to the original per-chunk engine and is
-kept as the bit-identical oracle.  Batched programs live in the AEAD
-shape-keyed compile cache, so steady-state streaming compiles nothing.
+kept as the bit-identical oracle.  Compiled programs live in shape-keyed
+caches, so steady-state streaming compiles nothing.
 
 Per-edge session keys come from a ``repro.attest.KeyDirectory``: every
 stage worker is measured (repro.attest.measure), enrolled, and admitted
@@ -113,6 +117,13 @@ def stage_table(st: Stage) -> Optional[np.ndarray]:
     return TABLE_OPS[st.op][1](st.const) if st.op in TABLE_OPS else None
 
 
+def stage_measurement(st: Stage) -> bytes:
+    """The measurement every worker of the stage is admitted under (a
+    table op's table digest included)."""
+    return measure_stage(op=st.op, const=st.const, fn=st.fn, sgx=st.sgx,
+                         table=stage_table(st))
+
+
 @dataclass
 class StageMetrics:
     """Per-stage counters behind ``Pipeline.report()`` (paper Fig. 6-8):
@@ -131,6 +142,10 @@ class StageMetrics:
     # stage's open->op->seal chain must DROP dispatches_per_window)
     windows: int = 0
     dispatches: int = 0
+
+    def cover(self, workers: int) -> None:
+        """Pad ``per_worker`` with zeros up to ``workers`` entries."""
+        self.per_worker.extend([0] * (workers - len(self.per_worker)))
 
     @property
     def dispatches_per_window(self) -> Optional[float]:
@@ -273,7 +288,7 @@ class Pipeline:
         # fault tolerance is opt-in the same way: ``retry`` is a
         # repro.ft.retry.RetryPolicy, ``chaos`` a repro.ft.chaos.ChaosPlan
         # (fault injection for tests/benchmarks).  When both are None the
-        # engine runs the original non-FT stage stream untouched.
+        # stage loop runs none of its fault-tolerance hooks.
         self.retry = retry
         self.chaos = chaos
         self._last_ft = None        # FTContext of the most recent run
@@ -329,14 +344,10 @@ class Pipeline:
         sessions are reused so a rescale does not re-key the stream.
         """
         d = self.directory
-        S = len(self.stages)
-        endpoints = ["io/source"] + [f"stage/{s.name}" for s in self.stages] \
-            + ["io/sink"]
         d.enroll("io/source", IO_ENDPOINT, allow=True)
         d.enroll("io/sink", IO_ENDPOINT, allow=True)
         for st in self.stages:
-            m = measure_stage(op=st.op, const=st.const, fn=st.fn, sgx=st.sgx,
-                              table=stage_table(st))
+            m = stage_measurement(st)
             d.policy.allow(m)
             d.enroll(f"stage/{st.name}", m)
             for w in range(max(1, st.workers)):
@@ -345,12 +356,20 @@ class Pipeline:
                     continue                     # stays evicted
                 d.enroll(wid, m)
                 d.admit(wid)                     # raises unless quote verifies
+        self._establish_edges(range(len(self.stages) + 1))
+
+    def _establish_edges(self, edges: Iterable[int]) -> None:
+        """Attested handshake for each listed edge that has no session
+        yet (edge i joins stage i-1 to stage i; the source and sink are
+        its ends).  Plain mode holds no keys, so it makes none."""
         if self.secure.mode == "plain":
-            return                               # no keys -> no handshakes
-        for i in range(S + 1):
-            if not d.has_session(f"edge{i}"):
-                d.establish(f"edge{i}", endpoints[i], endpoints[i + 1],
-                            stage_id=i)
+            return
+        d = self.directory
+        ends = ["io/source"] + [f"stage/{s.name}" for s in self.stages] \
+            + ["io/sink"]
+        for e in edges:
+            if not d.has_session(f"edge{e}"):
+                d.establish(f"edge{e}", ends[e], ends[e + 1], stage_id=e)
 
     def _provision_table(self, st: Stage):
         """The stage's table on the device.  Sealed modes seal it once
@@ -364,17 +383,17 @@ class Pipeline:
         h = self.directory.handle(edge)
         return seal_table(h.key(), h.next_counter(), stage_table(st))
 
-    def _table_kw(self, st: Stage) -> Dict[str, Any]:
-        """``table=`` for a stage that reads one, else nothing."""
-        return {"table": self.tables[st.name]} if st.name in self.tables \
-            else {}
-
-    def _live_workers(self, st: Stage) -> List[int]:
+    def _live_workers(self, st: Stage, ft=None,
+                      pool: Optional[List[EnclaveExecutor]] = None
+                      ) -> List[int]:
         """Worker indices still dispatchable.
 
         Full quote admission (sign + verify) happened at build/rescale;
         the only bit that can flip mid-stream is revocation, so the
-        per-window check is a set lookup, not a re-attestation.
+        per-window check is a set lookup, not a re-attestation.  Under
+        fault tolerance (``ft``) workers declared dead are left out too,
+        and when that leaves none, one spare is enrolled live into
+        ``pool`` as a last resort.
         """
         live = []
         for w in range(max(1, st.workers)):
@@ -392,55 +411,65 @@ class Pipeline:
             raise KeyDirectoryError(
                 f"every worker of stage {st.name!r} is revoked or "
                 f"inadmissible — nothing can process the edge")
+        if ft is not None:
+            live = [w for w in live if not ft.is_dead(st.name, w)] \
+                or [self._ft_enroll_spare(st, pool, ft)]
         return live
 
     # ------------------------------------------------------------------ run
 
-    def _worker_pool(self, i: int, st: Stage) -> List[EnclaveExecutor]:
-        """One executor per worker of stage i (paper: W identical workers
-        behind the stage's inbound router, all sharing the edge keys)."""
+    def _executor(self, i: int, st: Stage, w: int) -> EnclaveExecutor:
+        """Worker ``w`` of stage i: an executor under the stage's mode (a
+        non-sgx stage of an enclave pipeline runs encrypted), holding the
+        stage's inbound and outbound edge keys."""
         mode = self.secure.mode
         st_mode = mode if st.sgx else ("plain" if mode == "plain"
                                        else "encrypted")
-        pool = [EnclaveExecutor(st_mode, self.keys[i], self.keys[i + 1])
-                for _ in range(max(1, st.workers))]
-        for w, ex in enumerate(pool):
-            ex.tracer = self.tracer
-            ex.track = f"{st.name}/w{w}"
-        return pool
+        ex = EnclaveExecutor(st_mode, self.keys[i], self.keys[i + 1])
+        ex.tracer = self.tracer
+        ex.track = f"{st.name}/w{w}"
+        return ex
+
+    def _worker_pool(self, i: int, st: Stage) -> List[EnclaveExecutor]:
+        """One executor per worker of stage i (paper: W identical workers
+        behind the stage's inbound router, all sharing the edge keys)."""
+        return [self._executor(i, st, w) for w in range(max(1, st.workers))]
 
     def _stage_stream(self, upstream: Iterator[SealedWindow], st: Stage,
-                      pool: List[EnclaveExecutor],
-                      window_chunks: int) -> Iterator[SealedWindow]:
-        """Fan a window stream across the stage's workers.
+                      pool: List[EnclaveExecutor], window_chunks: int,
+                      ft=None) -> Iterator[SealedWindow]:
+        """Fan a window stream across the stage's workers, a round at a
+        time.  A round pulls ``len(live) * window_chunks`` rows,
+        round-robins them over the live workers by rolling global row
+        index (paper's Push socket: row g goes to worker g mod W, as in
+        the per-chunk engine) and runs each worker's share as ONE launch
+        (a device gather splits the window; a lone worker takes it
+        untouched).  MAC verdicts are deferred: the round syncs to host
+        ONCE (`_sync_window`), failed rows are dropped (reactive on_error
+        semantics) and counted, and survivors flow on in original stream
+        order.  Revocation is re-checked after the pull, so a worker
+        revoked mid-stream gets no rows from the next dispatch on.
 
-        Windows flow as batched device arrays; each round accumulates
-        ``len(live) * window_chunks`` rows, round-robins them over the
-        worker pool by rolling global row index (paper's Push socket —
-        row g goes to worker g mod W, exactly the per-chunk engine's
-        assignment), and runs each worker's share as ONE batched
-        open->op->seal dispatch (a device gather splits the window; the
-        single-worker steady state dispatches the window untouched).  MAC
-        verdicts are deferred: the whole round syncs to host ONCE
-        (`_sync_window`), failed rows are dropped (reactive on_error
-        semantics) and counted, and survivors flow downstream in original
-        stream order — the rr->fq composition of the per-chunk engine,
-        minus dropped rows.  Revocation is re-checked per round
-        (including revocations triggered while the window was being
-        pulled), so a worker revoked mid-stream stops receiving rows at
-        the next dispatch.
+        ``ft`` (a :class:`repro.ft.recovery.FTContext`) adds the
+        fault-tolerance hooks to the same round: its sealed inputs are
+        retained until its verdicts are folded in, each share goes
+        through :meth:`_ft_dispatch_share` (retry, failover, backup), and
+        rows whose verdicts failed or were lost are re-executed
+        (:meth:`_ft_replay`), so the surviving stream is bit-identical
+        to the fault-free run.
         """
         m = self.metrics[st.name]
-        if len(m.per_worker) < len(pool):
-            m.per_worker.extend([0] * (len(pool) - len(m.per_worker)))
+        m.cover(len(pool))
         tr = self.tracer
-        audit = self.directory.audit
         # instruments resolved ONCE per stage stream, not per window
         lat = _METRICS.histogram(f"pipeline.stage.{st.name}.window_seconds")
         depth = _METRICS.gauge(f"pipeline.stage.{st.name}.queue_rows")
+        # tamper and dropped-verdict faults exist only on sealed edges
+        faults = None if ft is None or self.secure.mode == "plain" \
+            else ft.chaos
         phase = 0                    # rolling global row index for rr
-        while True:
-            live = self._live_workers(st)
+        for rnd in itertools.count():
+            live = self._live_workers(st, ft, pool)
             target = len(live) * window_chunks
             parts: List[SealedWindow] = []
             got = 0
@@ -452,35 +481,50 @@ class Pipeline:
                 got += len(win)
             if not parts:
                 return
+            if ft is not None:
+                # retain the sealed inputs (still under their reserved
+                # nonce blocks) until this round's verdicts are folded in
+                ft.buffer.retain(st.name, rnd, parts)
             depth.set(got)
             # pulling the window may itself have revoked workers upstream
-            live = self._live_workers(st)
+            live = self._live_workers(st, ft, pool)
             L = len(live)
             d0 = _DISPATCHES.value
             t0 = time.perf_counter()
-            dispatches = []          # (part idx, worker, row idxs, out, ok)
+            shares = []              # (part idx, worker, row idxs, out, ok)
+            lost = set()             # shares whose verdicts never arrive
             wid = parts[0].window_id
             with tr.span("stage.dispatch", cat="dispatch", track=st.name,
                          rows=got, workers=L, window=wid):
                 for pi, win in enumerate(parts):
                     B = len(win)
-                    assign = [(phase + j) % L for j in range(B)]
-                    phase += B
                     for k in range(L):
-                        idxs = [j for j in range(B) if assign[j] == k]
+                        # rows j with (phase + j) % L == k
+                        idxs = list(range((k - phase) % L, B, L))
                         if not idxs:
                             continue
                         sub = win if len(idxs) == B else win.select(idxs)
                         w = live[k]
-                        if st.fn is not None:
-                            out, ok = pool[w].run_window(st.fn, sub)
-                        else:
-                            out, ok = pool[w].run_static_window(
-                                st.op, st.const, sub, **self._table_kw(st))
-                        dispatches.append((pi, w, idxs, out, ok))
+                        if ft is None:
+                            shares.append((pi, w, idxs)
+                                          + self._exec_share(st, pool[w], sub))
+                            continue
+                        if faults is not None:
+                            tf = faults.tamper_for(st.name, rnd, w)
+                            if tf is not None:
+                                # corrupt the dispatch COPY only — the
+                                # retained rows stay clean for replay
+                                sub = faults.apply_tamper(tf, sub)
+                        w2, out, ok = self._ft_dispatch_share(
+                            st, pool, ft, rnd, w, sub, ft.next_share_id())
+                        if faults is not None and faults.drop_verdict_for(
+                                st.name, rnd, w) is not None:
+                            lost.add(len(shares))
+                        shares.append((pi, w2, idxs, out, ok))
+                    phase += B
             verdicts = _sync_window(
-                [d[3].words for d in dispatches],
-                [(d[4], len(d[3])) for d in dispatches],
+                [d[3].words for d in shares],
+                [(d[4], len(d[3])) for d in shares],
                 tracer=tr, track=st.name, window=wid)
             # honest window timing: t0 -> after block_until_ready, so
             # throughput_mbps reflects execution, not async enqueue
@@ -488,42 +532,70 @@ class Pipeline:
             m.seconds += dt
             lat.observe(dt)
             m.windows += 1
-            off = 0
-            marks: List[np.ndarray] = []
-            for pi, w, idxs, out, _ in dispatches:
-                v = verdicts[off: off + len(idxs)]
-                off += len(idxs)
-                marks.append(v)
-                for jj, alive in enumerate(v):
-                    if alive:
-                        m.chunks += 1
-                        m.per_worker[w] += 1
-                        m.bytes += int(parts[pi].n_words) * 4
-                    else:
-                        m.mac_failures += 1
-                        pool[w].errors += 1
-                        audit.record("mac_failure", stage=st.name,
-                                     worker=self.worker_id(st.name, w),
-                                     row=out.counters[jj],
-                                     epoch=out.epochs[jj])
+            final, marks = self._fold_verdicts(st, pool, parts, shares,
+                                               verdicts, lost=lost)
+            if ft is not None:
+                self._ft_replay(st, pool, ft, rnd, parts, live, shares,
+                                lost, final, marks)
             with tr.span("stage.merge", cat="pipeline", track=st.name,
                          windows=len(parts), window=wid):
-                merged = list(self._merge_outputs(parts, dispatches, marks))
-            # the round's launches: its shares, their gathers, the merge
+                merged = list(self._merge_outputs(parts, final, marks))
+            # the round's launches: shares, retries, replays, the merge
             disp = _DISPATCHES.value - d0
             m.dispatches += disp
             mon = self.monitor
             if mon.enabled:
                 wrows: Dict[int, int] = {}
-                for _, w, idxs, _, _ in dispatches:
+                for _, w, idxs, _, _ in final:
                     wrows[w] = wrows.get(w, 0) + len(idxs)
                 mon.record_window(
-                    st.name, rows=got, ok_rows=int(verdicts.sum()),
+                    st.name, rows=got,
+                    ok_rows=sum(int(v.sum()) for v in marks),
                     bytes=sum(len(p) * int(p.n_words) * 4 for p in parts),
                     seconds=dt, queue_rows=got, worker_rows=wrows,
                     min_epoch=min(min(p.epochs) for p in parts),
                     dispatches=disp)
+            if ft is not None:
+                # the round's verdicts are folded in: release its rows
+                ft.buffer.ack(st.name, rnd)
             yield from merged
+
+    def _fold_verdicts(self, st: Stage, pool: List[EnclaveExecutor],
+                       parts: List[SealedWindow], shares, verdicts,
+                       lost=(), counted=()):
+        """Fold one verdict sync (the rows of ``shares`` in order) into
+        the stage's metrics: a verified row counts toward ``chunks``,
+        ``per_worker`` and ``bytes``; a failed row is a ``mac_failure``
+        of its worker, counted on its executor and audited.  A share in
+        ``lost`` (its verdicts never reached the host) counts nothing; one
+        in ``counted`` (a replay of rows whose failure was counted
+        already) counts only its rows that now verify.  Returns the
+        shares that go on to the merge and their per-row marks."""
+        m = self.metrics[st.name]
+        audit = self.directory.audit
+        final, marks = [], []
+        off = 0
+        for si, share in enumerate(shares):
+            pi, w, idxs, out, _ = share
+            v = verdicts[off: off + len(idxs)]
+            off += len(idxs)
+            if si in lost:
+                continue
+            final.append(share)
+            marks.append(v)
+            for jj, alive in enumerate(v):
+                if alive:
+                    m.chunks += 1
+                    m.per_worker[w] += 1
+                    m.bytes += int(parts[pi].n_words) * 4
+                elif si not in counted:
+                    m.mac_failures += 1
+                    pool[w].errors += 1
+                    audit.record("mac_failure", stage=st.name,
+                                 worker=self.worker_id(st.name, w),
+                                 row=out.counters[jj],
+                                 epoch=out.epochs[jj])
+        return final, marks
 
     @staticmethod
     def _merge_outputs(parts, dispatches, marks):
@@ -583,36 +655,32 @@ class Pipeline:
         base, epoch = h0.reserve_window(n)
         return (list(range(base, base + n)), epoch)
 
-    def _ft_exec(self, st: Stage, ex: EnclaveExecutor, sub: SealedWindow,
-                 coords):
-        """One batched open->op->seal of a share.  ``coords`` =
-        (counters, epoch) re-seals under fresh ingress-reserved
-        coordinates (the re-execution path); None keeps steady state."""
+    def _exec_share(self, st: Stage, ex: EnclaveExecutor, sub: SealedWindow,
+                    coords=None):
+        """One batched open->op->seal of a share: the stage's op, reading
+        its table if it has one.  ``coords`` = (counters, epoch) re-seals
+        under fresh ingress-reserved coordinates (a re-execution); None
+        keeps the rows' own (steady state)."""
+        kw = {} if coords is None else {"reseal_as": coords}
         if st.fn is not None:
-            return ex.run_window(st.fn, sub, reseal_as=coords)
-        return ex.run_static_window(st.op, st.const, sub, reseal_as=coords,
-                                    **self._table_kw(st))
+            return ex.run_window(st.fn, sub, **kw)
+        if st.name in self.tables:
+            kw["table"] = self.tables[st.name]
+        return ex.run_static_window(st.op, st.const, sub, **kw)
 
     def _ft_pick_survivor(self, st: Stage, ft, exclude: int,
                           prefer=None) -> Optional[int]:
-        """A live, not-dead worker other than ``exclude`` — honoring the
-        backup dispatcher's placement hint when it is usable.
-
-        Recomputed from the CURRENT worker set (not the round-start live
-        list): a spare enrolled earlier in the same round must absorb
-        later failovers instead of triggering more enrollments."""
-        cands = []
-        for x in range(max(1, st.workers)):
-            if x == exclude or ft.is_dead(st.name, x):
-                continue
-            if self.directory.policy.is_revoked(self.worker_id(st.name, x)):
-                continue
-            cands.append(x)
-        if not cands:
-            return None
-        if prefer is not None and prefer in cands:
+        """A live, not-dead worker other than ``exclude``: the backup
+        dispatcher's hint ``prefer`` when usable.  Read from the CURRENT
+        worker set, not the round's, so a spare enrolled earlier in the
+        round absorbs later failovers instead of enrolling more."""
+        cands = [x for x in range(max(1, st.workers))
+                 if x != exclude and not ft.is_dead(st.name, x)
+                 and not self.directory.policy.is_revoked(
+                     self.worker_id(st.name, x))]
+        if prefer in cands:
             return prefer
-        return cands[0]
+        return cands[0] if cands else None
 
     def enroll_spare(self, stage_name: str) -> int:
         """Enroll + admit one spare worker for a stage, live.
@@ -630,46 +698,32 @@ class Pipeline:
         d = self.directory
         w = max(1, st.workers)
         wid = self.worker_id(st.name, w)
-        meas = measure_stage(op=st.op, const=st.const, fn=st.fn, sgx=st.sgx)
+        meas = stage_measurement(st)
         d.policy.allow(meas)
         d.enroll(wid, meas)
         d.admit(wid)                 # raises unless the quote verifies
-        if self.secure.mode != "plain":
-            endpoints = ["io/source"] \
-                + [f"stage/{s.name}" for s in self.stages] + ["io/sink"]
-            for e in (idx, idx + 1):
-                if not d.has_session(f"edge{e}"):
-                    d.establish(f"edge{e}", endpoints[e], endpoints[e + 1],
-                                stage_id=e)
+        self._establish_edges((idx, idx + 1))
         st.workers = w + 1
         return w
 
     def _ft_enroll_spare(self, st: Stage, pool: List[EnclaveExecutor],
-                         ft) -> Optional[int]:
-        """Failover fallback when a stage has no survivors: enroll a
+                         ft) -> int:
+        """Failover's last resort when a stage has no survivors: enroll a
         spare through the live admission path and extend the worker pool.
         A rejected handshake (chaos ``enroll_fail``) is retried once with
-        the next spare id; None if no spare could be admitted."""
+        the next spare id; raises if no spare could be admitted."""
         for _ in range(2):
             try:
                 w = self.enroll_spare(st.name)
             except QuoteError:
                 ft.enroll_failures.inc()
                 continue
-            i = next(ix for ix, s in enumerate(self.stages)
-                     if s.name == st.name)
-            mode = self.secure.mode
-            st_mode = mode if st.sgx else ("plain" if mode == "plain"
-                                           else "encrypted")
-            ex = EnclaveExecutor(st_mode, self.keys[i], self.keys[i + 1])
-            ex.tracer = self.tracer
-            ex.track = f"{st.name}/w{w}"
-            pool.append(ex)
-            m = self.metrics[st.name]
-            if len(m.per_worker) < len(pool):
-                m.per_worker.extend([0] * (len(pool) - len(m.per_worker)))
+            pool.append(self._executor(self.stages.index(st), st, w))
+            self.metrics[st.name].cover(len(pool))
             return w
-        return None
+        raise KeyDirectoryError(
+            f"stage {st.name!r} has no worker left to take a share and "
+            f"no spare could be admitted")
 
     def _ft_dispatch_share(self, st: Stage, pool: List[EnclaveExecutor],
                            ft, rnd: int, w: int,
@@ -703,7 +757,7 @@ class Pipeline:
             if not dead and (spec is None or spec.when == "after"):
                 coords = self._ft_fresh_coords(len(sub)) if fresh else None
                 t0 = time.perf_counter()
-                out, ok = self._ft_exec(st, pool[w], sub, coords)
+                out, ok = self._exec_share(st, pool[w], sub, coords)
                 dt = time.perf_counter() - t0
             if spec is not None:
                 # the fault fires exactly once: one worker_failed per
@@ -740,7 +794,8 @@ class Pipeline:
                 if w2 is None:
                     raise KeyDirectoryError(
                         f"share of stage {st.name!r} has no survivor to "
-                        f"fail over to and no spare could be admitted")
+                        f"fail over to and the retry policy enrolls no "
+                        f"spare")
                 ft.failovers.inc()
                 audit.record("share_failover", stage=st.name,
                              worker=self.worker_id(st.name, w),
@@ -755,8 +810,8 @@ class Pipeline:
             stall = None if chaos is None \
                 else chaos.stall_for(st.name, rnd, w)
             if stall is not None:
-                observed = dt + stall.seconds
-                if observed > policy.timeout_for(det):
+                dt += stall.seconds      # the straggler's observed time
+                if dt > policy.timeout_for(det):
                     ft.worker_failures.inc()
                     audit.record("worker_failed", stage=st.name,
                                  worker=self.worker_id(st.name, w),
@@ -774,213 +829,63 @@ class Pipeline:
                                      reason="backup", round=rnd)
                         coords = self._ft_fresh_coords(len(sub))
                         t0 = time.perf_counter()
-                        out2, ok2 = self._ft_exec(st, pool[w2], sub,
-                                                  coords)
+                        out2, ok2 = self._exec_share(st, pool[w2], sub,
+                                                     coords)
                         det.observe(time.perf_counter() - t0)
                         bdisp.track(share_id, w2)
                         bdisp.complete(share_id)   # backup completes...
                         bdisp.complete(share_id)   # ...original is a dup
                         return w2, out2, ok2
                     # nobody to back up on: keep the slow result
-                det.observe(observed)
-                bdisp.complete(share_id)
-                return w, out, ok
             det.observe(dt)
             bdisp.complete(share_id)
             return w, out, ok
 
-    def _stage_stream_ft(self, upstream: Iterator[SealedWindow], st: Stage,
-                         pool: List[EnclaveExecutor], window_chunks: int,
-                         ft) -> Iterator[SealedWindow]:
-        """Fault-tolerant sibling of :meth:`_stage_stream`.
-
-        Same round structure (pull -> round-robin -> one batched
-        dispatch per worker share -> ONE deferred-verdict host sync ->
-        merge in stream order), with the fault-tolerance hooks around
-        it: the round's sealed input parts are RETAINED in the replay
-        buffer until its verdicts are folded in; each share dispatch
-        goes through :meth:`_ft_dispatch_share` (chaos crash/stall
-        hooks, retry/backoff, failover, speculative backup); tampered
-        shares MAC-fail at the sync and their rows are re-executed from
-        the retained clean parts; a dropped verdict sync voids the whole
-        share, which is likewise replayed.  Replayed rows re-seal under
-        fresh ingress counters, and the merge still orders by original
-        row index — so the surviving stream, and any terminal reduce
-        over it, is bit-identical to the fault-free run.
-        """
-        m = self.metrics[st.name]
-        if len(m.per_worker) < len(pool):
-            m.per_worker.extend([0] * (len(pool) - len(m.per_worker)))
-        tr = self.tracer
+    def _ft_replay(self, st: Stage, pool: List[EnclaveExecutor], ft,
+                   rnd: int, parts: List[SealedWindow], live: List[int],
+                   shares, lost, final, marks) -> None:
+        """Re-execute from the round's retained clean parts every share
+        whose verdicts were dropped and, when the policy says so, every
+        MAC-failed row, each re-sealed under fresh ingress coordinates;
+        the replays share ONE extra verdict sync, and are appended to
+        ``final``/``marks``.  A MAC-failed row that fails again is not
+        counted twice; a verdict-dropped row is, on its first failure."""
+        replays = []                 # (part idx, worker, row idxs, reason)
+        kept = iter(marks)
+        for si, (pi, w, idxs, _, _) in enumerate(shares):
+            if si in lost:
+                # the host never saw this share's verdicts: every row is
+                # unverified -> replay the whole share
+                replays.append((pi, w, list(idxs), "verdict_dropped"))
+                continue
+            v = next(kept)
+            if ft.policy.replay_mac_failures and not v.all():
+                replays.append((pi, w, [j for jj, j in enumerate(idxs)
+                                        if not v[jj]], "mac_failure"))
+        if not replays:
+            return
         audit = self.directory.audit
-        chaos = ft.chaos
-        secure = self.secure.mode != "plain"
-        lat = _METRICS.histogram(f"pipeline.stage.{st.name}.window_seconds")
-        depth = _METRICS.gauge(f"pipeline.stage.{st.name}.queue_rows")
-        phase = 0
-        rnd = -1
-        while True:
-            rnd += 1
-            live = [w for w in self._live_workers(st)
-                    if not ft.is_dead(st.name, w)]
-            if not live:
-                # every worker is dead: last-ditch live spare enrollment
-                w = self._ft_enroll_spare(st, pool, ft)
-                if w is None:
-                    raise KeyDirectoryError(
-                        f"every worker of stage {st.name!r} is dead and "
-                        f"no spare could be admitted")
-                live = [w]
-            target = len(live) * window_chunks
-            parts: List[SealedWindow] = []
-            got = 0
-            while got < target:
-                win = next(upstream, None)
-                if win is None:
-                    break
-                parts.append(win)
-                got += len(win)
-            if not parts:
-                return
-            # retain the sealed inputs (still under their reserved nonce
-            # blocks) until this round's verdict sync is folded in
-            ft.buffer.retain(st.name, rnd, parts)
-            depth.set(got)
-            live = [w for w in self._live_workers(st)
-                    if not ft.is_dead(st.name, w)]
-            L = len(live)
-            d0 = _DISPATCHES.value
-            t0 = time.perf_counter()
-            dispatches = []          # (part idx, worker, row idxs, out, ok)
-            flags = []               # aligned: per-share fault markers
-            wid = parts[0].window_id
-            with tr.span("stage.dispatch", cat="dispatch", track=st.name,
-                         rows=got, workers=L, window=wid):
-                for pi, win in enumerate(parts):
-                    B = len(win)
-                    assign = [(phase + j) % L for j in range(B)]
-                    phase += B
-                    for k in range(L):
-                        idxs = [j for j in range(B) if assign[j] == k]
-                        if not idxs:
-                            continue
-                        sub = win if len(idxs) == B else win.select(idxs)
-                        w = live[k]
-                        tampered = False
-                        if secure and chaos is not None:
-                            tf = chaos.tamper_for(st.name, rnd, w)
-                            if tf is not None:
-                                # corrupt the dispatch COPY only — the
-                                # retained rows stay clean for replay
-                                sub = chaos.apply_tamper(tf, sub)
-                                tampered = True
-                        share_id = ft.next_share_id()
-                        w2, out, ok = self._ft_dispatch_share(
-                            st, pool, ft, rnd, w, sub, share_id)
-                        verdict_dropped = False
-                        if secure and chaos is not None:
-                            dv = chaos.drop_verdict_for(st.name, rnd, w)
-                            verdict_dropped = dv is not None
-                        dispatches.append((pi, w2, idxs, out, ok))
-                        flags.append({"tampered": tampered,
-                                      "verdict_dropped": verdict_dropped})
-            verdicts = _sync_window(
-                [d[3].words for d in dispatches],
-                [(d[4], len(d[3])) for d in dispatches],
-                tracer=tr, track=st.name, window=wid)
-            dt = time.perf_counter() - t0
-            m.seconds += dt
-            lat.observe(dt)
-            m.windows += 1
-            # ---- per-row accounting + replay scheduling
-            off = 0
-            final = []               # dispatch tuples fed to the merge
-            marks: List[np.ndarray] = []
-            replays = []             # (part idx, worker, row js, reason)
-            for di, (pi, w, idxs, out, _) in enumerate(dispatches):
-                v = np.array(verdicts[off: off + len(idxs)], copy=True)
-                off += len(idxs)
-                if flags[di]["verdict_dropped"]:
-                    # the host never saw this share's verdicts: every
-                    # row is unverified -> replay the whole share
-                    replays.append((pi, w, list(idxs), "verdict_dropped"))
-                    continue
-                for jj, alive_row in enumerate(v):
-                    if alive_row:
-                        m.chunks += 1
-                        m.per_worker[w] += 1
-                        m.bytes += int(parts[pi].n_words) * 4
-                    else:
-                        m.mac_failures += 1
-                        pool[w].errors += 1
-                        audit.record("mac_failure", stage=st.name,
-                                     worker=self.worker_id(st.name, w),
-                                     row=out.counters[jj],
-                                     epoch=out.epochs[jj])
-                final.append((pi, w, idxs, out, None))
-                marks.append(v)
-                failed_js = [j for jj, j in enumerate(idxs) if not v[jj]]
-                if failed_js and secure and ft.policy.replay_mac_failures:
-                    replays.append((pi, w, failed_js, "mac_failure"))
-            if replays:
-                rd = []
-                for pi, w, row_js, reason in replays:
-                    sub = parts[pi].select(row_js)
-                    coords = self._ft_fresh_coords(len(sub))
-                    wr = w if not ft.is_dead(st.name, w) else live[0]
-                    out2, ok2 = self._ft_exec(st, pool[wr], sub, coords)
-                    rd.append((pi, wr, row_js, out2, ok2))
-                    ft.replays.inc()
-                    audit.record("window_replayed", stage=st.name,
-                                 worker=self.worker_id(st.name, wr),
-                                 rows=len(row_js), reason=reason,
-                                 round=rnd)
-                rv = _sync_window([d[3].words for d in rd],
-                                  [(d[4], len(d[3])) for d in rd],
-                                  tracer=tr, track=st.name, window=wid)
-                roff = 0
-                for (pi, _, row_js, reason), (pi2, wr, _, out2, _) \
-                        in zip(replays, rd):
-                    v2 = np.array(rv[roff: roff + len(row_js)], copy=True)
-                    roff += len(row_js)
-                    for jj, alive_row in enumerate(v2):
-                        if alive_row:
-                            m.chunks += 1
-                            m.per_worker[wr] += 1
-                            m.bytes += int(parts[pi].n_words) * 4
-                        elif reason == "verdict_dropped":
-                            # first time this row provably failed
-                            m.mac_failures += 1
-                            audit.record(
-                                "mac_failure", stage=st.name,
-                                worker=self.worker_id(st.name, wr),
-                                row=out2.counters[jj],
-                                epoch=out2.epochs[jj])
-                        # a mac_failure replay that fails again was
-                        # already audited on the original verdict
-                    final.append((pi, wr, row_js, out2, None))
-                    marks.append(v2)
-            with tr.span("stage.merge", cat="pipeline", track=st.name,
-                         windows=len(parts), window=wid):
-                merged = list(self._merge_outputs(parts, final, marks))
-            # the round's launches: shares, retries, replays, the merge
-            disp = _DISPATCHES.value - d0
-            m.dispatches += disp
-            mon = self.monitor
-            if mon.enabled:
-                wrows: Dict[int, int] = {}
-                for _, w, idxs, _, _ in final:
-                    wrows[w] = wrows.get(w, 0) + len(idxs)
-                mon.record_window(
-                    st.name, rows=got,
-                    ok_rows=int(sum(int(v.sum()) for v in marks)),
-                    bytes=sum(len(p) * int(p.n_words) * 4 for p in parts),
-                    seconds=dt, queue_rows=got, worker_rows=wrows,
-                    min_epoch=min(min(p.epochs) for p in parts),
-                    dispatches=disp)
-            # the round's verdicts are folded in: release retained rows
-            ft.buffer.ack(st.name, rnd)
-            yield from merged
+        rd = []
+        for pi, w, row_js, reason in replays:
+            sub = parts[pi].select(row_js)
+            coords = self._ft_fresh_coords(len(sub))
+            wr = w if not ft.is_dead(st.name, w) else live[0]
+            rd.append((pi, wr, row_js)
+                      + self._exec_share(st, pool[wr], sub, coords))
+            ft.replays.inc()
+            audit.record("window_replayed", stage=st.name,
+                         worker=self.worker_id(st.name, wr),
+                         rows=len(row_js), reason=reason, round=rnd)
+        rv = _sync_window([d[3].words for d in rd],
+                          [(d[4], len(d[3])) for d in rd],
+                          tracer=self.tracer, track=st.name,
+                          window=parts[0].window_id)
+        rfinal, rmarks = self._fold_verdicts(
+            st, pool, parts, rd, rv,
+            counted={ri for ri, r in enumerate(replays)
+                     if r[3] == "mac_failure"})
+        final.extend(rfinal)
+        marks.extend(rmarks)
 
     def _ingress_stream(self, source: Iterable[jax.Array], mode: str,
                         rekey_every_n: Optional[int],
@@ -1228,13 +1133,9 @@ class Pipeline:
         reduce_idx = next((i for i, s in enumerate(self.stages)
                            if s.reduce_fn is not None), None)
         end = len(self.stages) if reduce_idx is None else reduce_idx
-        for i in range(end):
-            st = self.stages[i]
-            pool = self._worker_pool(i, st)
-            if ft is not None:
-                stream = self._stage_stream_ft(stream, st, pool, wc, ft)
-            else:
-                stream = self._stage_stream(stream, st, pool, wc)
+        for i, st in enumerate(self.stages[:end]):
+            stream = self._stage_stream(stream, st,
+                                        self._worker_pool(i, st), wc, ft)
         sink_w = max(1, self.stages[end - 1].workers) if end else 1
         egress_rows = sink_w * wc
 
@@ -1379,8 +1280,7 @@ class Pipeline:
         blocking ``bool(ok)`` host sync per chunk — round-robin dispatch
         over the pool, fair-queue merge of the worker sub-streams."""
         m = self.metrics[st.name]
-        if len(m.per_worker) < len(pool):
-            m.per_worker.extend([0] * (len(pool) - len(m.per_worker)))
+        m.cover(len(pool))
         tr = self.tracer
         mon = self.monitor
         audit = self.directory.audit
@@ -1523,10 +1423,10 @@ class Pipeline:
         p._egress_windows_n = self._egress_windows_n
         p._egress_dispatches = self._egress_dispatches
         for sname, m in self.metrics.items():
-            pw = list(m.per_worker)
-            if sname == name and len(pw) < workers:
-                pw.extend([0] * (workers - len(pw)))
-            p.metrics[sname] = dataclasses.replace(m, per_worker=pw)
+            p.metrics[sname] = dataclasses.replace(
+                m, per_worker=list(m.per_worker))
+            if sname == name:
+                p.metrics[sname].cover(workers)
         return p
 
     def report(self) -> Dict[str, Dict[str, Any]]:

@@ -267,6 +267,81 @@ def test_chaos_plan_replays_bit_for_bit():
     assert np.array_equal(out1, _oracle())
 
 
+# ------------------------------------------- fault-free rounds under retry
+
+
+@pytest.mark.parametrize("mode", ["plain", "encrypted", "enclave"])
+def test_fault_free_ft_round_matches_the_plain_round(mode, monkeypatch):
+    """With a retry policy and no fault, the stage loop runs the same
+    rounds as without fault tolerance: the same windows reach the sink
+    (words, tags, counters, epochs), with the same launches and host
+    syncs, no fault-tolerance audit event and no row left retained."""
+    from repro.core import pipeline as P
+
+    sts = [Stage("a", "scale_f32", const=1.5, workers=2),
+           Stage("b", "relu_f32", workers=2),
+           Stage("sink", "custom", reduce_fn=_sum_reduce,
+                 reduce_init=None)]
+    src = [jnp.asarray(
+        np.random.RandomState(3 + i).rand(32).astype(np.float32) - 0.5)
+        for i in range(12)]
+    seen = []
+    real_open = Pipeline._open_egress
+
+    def spy(self, parts, mode, key):
+        seen.append([(np.asarray(w.words),
+                      None if w.tags is None else np.asarray(w.tags),
+                      list(w.counters), list(w.epochs)) for w in parts])
+        return real_open(self, parts, mode, key)
+
+    monkeypatch.setattr(Pipeline, "_open_egress", spy)
+
+    def run(retry):
+        p = Pipeline(sts, SecureStreamConfig(mode=mode), seed=3,
+                     directory=KeyDirectory(seed=3), window_chunks=2)
+        seen.clear()
+        d0, s0 = P.dispatch_count(), P.host_sync_count()
+        out = np.asarray(p.run(iter(src), retry=retry))
+        return (p, out, list(seen), P.dispatch_count() - d0,
+                P.host_sync_count() - s0)
+
+    _, out0, wins0, disp0, syncs0 = run(None)
+    p, out1, wins1, disp1, syncs1 = run(RetryPolicy())
+    assert np.array_equal(out0, out1)
+    assert len(wins1) == len(wins0) > 1
+    for got, want in zip(wins1, wins0):
+        assert len(got) == len(want)
+        for (w1, t1, c1, e1), (w0, t0, c0, e0) in zip(got, want):
+            assert np.array_equal(w1, w0)
+            assert (t1 is None) == (t0 is None)
+            assert t1 is None or np.array_equal(t1, t0)
+            assert (c1, e1) == (c0, e0)
+    assert (disp1, syncs1) == (disp0, syncs0)
+    assert _ft_events(p.directory.audit, "worker_failed", "share_retried",
+                      "share_failover", "window_replayed") == []
+    # the hooks ran: every stage retained and acked its rounds
+    buf = p._last_ft.buffer
+    assert buf.watermark() >= 1 and buf.retained_rows() == 0
+
+
+def test_a_stall_under_the_cutoff_feeds_the_straggler_detector():
+    """A stalled share whose observed time stays under the cutoff keeps
+    its result, and the stage's straggler detector observes the stalled
+    time (execution plus the stall), not the bare execution time."""
+    plan = ChaosPlan(faults=[
+        FaultSpec("stall", stage="s0", round=0, worker=0, seconds=4.0)])
+    p = _build(chaos=plan, retry=RetryPolicy(share_timeout_s=100.0))
+    out = p.run(iter(_source()))
+    assert np.array_equal(_oracle(), np.asarray(out))
+    assert not plan.pending()
+    assert _ft_events(p.directory.audit, "worker_failed",
+                      "share_failover") == []
+    det = p._last_ft.detector("s0")
+    # 3 rounds of one share: still warming up, so mean is the plain mean
+    assert det.n == N_CHUNKS // 4 <= det.warmup
+    assert det.mean * det.n >= 4.0
+
+
 # ----------------------------------------------------- engine interlocks
 
 

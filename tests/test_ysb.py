@@ -174,6 +174,16 @@ def test_a_swapped_table_changes_the_measurement(monkeypatch):
         != q.directory._workers["stage/join"]
 
 
+def test_a_spare_of_the_join_stage_is_measured_with_its_table():
+    """A spare enrolled live is admitted under its stage's measurement,
+    the table's digest included, like the workers built with it."""
+    p = Pipeline([Stage("join", op="ysb_campaign_join", const=SEED)],
+                 seed=0)
+    w = p.enroll_spare("join")
+    workers = p.directory._workers
+    assert workers[Pipeline.worker_id("join", w)] == workers["stage/join"]
+
+
 def test_the_sealed_table_is_not_the_table():
     p = _job().build("enclave")
     sealed = p.tables["join"]
